@@ -982,10 +982,20 @@ def sharded_serve_scenario(arch: str = "qwen1.5-4b", device_counts=(1, 2, 4),
     along for honesty.  ``token_identical`` asserts the engine contract:
     identical transcripts at every device count.  qwen1.5-4b smoke is the
     default arch (its kvH = 4 shards 4 ways; qwen2-1.5b's kvH = 2 cannot).
+
+    CPU only: a parent that has touched JAX holds the chip, so forked
+    children could only ever measure CPU devices on a chip host.  The
+    four-chip path is ``python chip_smoke.py --four-chips``.
     """
     import os
     import subprocess
     import sys
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "sharded_serve_scenario forks CPU-device children and cannot "
+            "measure a TPU; run the four-chip path with "
+            "`python chip_smoke.py --four-chips` instead")
 
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")

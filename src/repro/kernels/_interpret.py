@@ -5,15 +5,11 @@ matmul, rmsnorm) can import it at the top level without a cycle through
 ``kernels.ops`` (which imports all of them); ``kernels.ops`` re-exports
 ``default_interpret`` as the public name.
 
-IMPORTANT: callers must resolve the flag BEFORE a jit boundary (pass a
-concrete bool as the static ``interpret`` argument).  Resolving inside a
-jitted body would bake the environment's value into the cached trace under
-the static key ``None`` — later changes to REPRO_PALLAS_INTERPRET would be
-silently ignored.
+Callers resolve the flag BEFORE a jit boundary and pass a concrete bool as
+the static ``interpret`` argument, so the cached trace is keyed on the mode
+it was built for.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -21,11 +17,6 @@ import jax
 def default_interpret() -> bool:
     """False iff the active backend is a real TPU (the kernels then compile
     through Mosaic); True everywhere else (CPU CI runs the kernels in
-    interpret mode).  ``REPRO_PALLAS_INTERPRET=0|1`` (also ``false|true``)
-    forces either mode — e.g. ``=0`` to exercise the compile path in a TPU
-    simulator, ``=1`` to debug numerics on a TPU host.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None and env.strip().lower() in ("0", "1", "false", "true"):
-        return env.strip().lower() in ("1", "true")
+    interpret mode).  Nothing overrides it: on a TPU the kernels always
+    compile, so a chip run can never measure the interpreter."""
     return jax.default_backend() != "tpu"

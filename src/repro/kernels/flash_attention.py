@@ -10,20 +10,18 @@ EXPERIMENTS.md §Perf).
 Layouts: q (BH, S, hd); k, v (BKV, S, hd) with BH = B·kvH·G, BKV = B·kvH.
 Grid = (BH, nq, nk), K innermost.
 
-The two paged serving kernels (``paged_flash_decode``,
-``ragged_paged_flash``) additionally support **int8 quantized KV pools**:
-when the pool dtype is int8, per-entry-per-KV-head float32 scale pools
-(``ks``/``vs``, shape (n_pages, page, kvH)) ride in through the same
-block-table indirection, and each page tile is dequantized IN VMEM right
-after its DMA — ``k = int8_tile * scale_row`` feeding the unchanged fp32
-online-softmax accumulate.  HBM traffic per page is therefore the int8
-bytes plus one scale row (~hd/4× less than fp32 KV), never a dequantized
-copy — the serving analogue of the paper's point that keeping the working
-set in fast memory, not adding FLOPs, is what moves the bound.
+The paged serving kernel (``ragged_paged_flash``; the two-phase decode
+step calls it with one query row per slot) additionally supports **int8
+quantized KV pools**: when the pool dtype is int8, per-entry-per-KV-head
+float32 scale pools (``ks``/``vs``, shape (n_pages, kvH, 1, page)) ride in
+through the same block-table indirection, and each page tile's scales are
+applied IN VMEM right after its DMA, feeding the unchanged fp32
+online-softmax accumulate.  HBM traffic per page is therefore the int8 bytes plus one
+scale row (~hd/4× less than fp32 KV), never a dequantized copy.
 
 ``interpret=None`` on every entry point resolves through
 ``kernels.ops.default_interpret()``: compiled for real on TPU backends,
-interpret mode elsewhere (CPU CI), overridable via REPRO_PALLAS_INTERPRET.
+interpret mode elsewhere (CPU CI).
 """
 from __future__ import annotations
 
@@ -86,146 +84,26 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 # ---------------------------------------------------------------------------
-# Paged flash decode (serving): single-token attention over a block-table-
-# indexed KV pool.  The block table rides in as a scalar-prefetch argument so
-# the BlockSpec index_map can resolve page -> pool-row indirection before
-# each grid step's DMA — the kernel body itself never sees the indirection,
-# only a dense (page_size, hd) tile (plus, for int8 pools, its (page_size,)
-# scale row, dequantized here in VMEM).  Grid = (B, kvH, n_pages_per_slot)
-# with the page dimension innermost (sequential online-softmax state in
-# VMEM).
-
-
-def _paged_decode_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
-                         page: int, npages: int, scale: float,
-                         quantized: bool):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-    b, ji = pl.program_id(0), pl.program_id(2)
-
-    @pl.when(ji == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    n_valid = lens_ref[b] - ji * page  # written entries in this page
-
-    @pl.when(n_valid > 0)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (page, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (page, hd)
-        if quantized:  # fused dequant: int8 page tile × its scale row
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, page)
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols < n_valid, s, NEG_INF)
-
-        m_prev = m_ref[...]  # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(cols < n_valid, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(p, v)
-        m_ref[...] = m_new
-
-    @pl.when(ji == pl.num_programs(2) - 1)
-    def _flush():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def paged_flash_decode(q, kp, vp, ptab, lens, ks=None, vs=None, *,
-                       interpret=None):
-    """Decode-step attention over a paged KV pool.
-
-    q: (B, kvH, G, hd); kp, vp: (n_pages, page, kvH, hd);
-    ptab: (B, pps) int32 block table (entries >= n_pages = unmapped);
-    lens: (B,) int32 valid entries per slot.  Returns (B, kvH, G, hd).
-    Full (non-windowed) causal layers only — every written entry is visible
-    to the single query token.
-
-    int8 pools: pass ``ks``/``vs`` ((n_pages, page, kvH) float32 scale
-    pools); the page tiles are dequantized in VMEM inside the online-softmax
-    loop, so the fp32 accumulate is unchanged while the page DMA moves ~4×
-    fewer bytes.
-    """
-    # resolve OUTSIDE the jit boundary: a concrete bool is the static key,
-    # so a later REPRO_PALLAS_INTERPRET change retraces instead of silently
-    # reusing a cache entry keyed on None
-    if interpret is None:
-        interpret = default_interpret()
-    return _paged_flash_decode(q, kp, vp, ptab, lens, ks, vs,
-                               interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_flash_decode(q, kp, vp, ptab, lens, ks, vs, *, interpret):
-    B, kvH, G, hd = q.shape
-    npages, page = kp.shape[0], kp.shape[1]
-    pps = ptab.shape[1]
-    scale = hd ** -0.5
-    quantized = ks is not None
-
-    def _page_idx(b, h, j, ptab_ref, lens_ref):
-        # unmapped sentinel pages clamp to a real pool row; their entries
-        # are dead via the lens mask in the kernel body
-        return (jnp.minimum(ptab_ref[b, j], npages - 1), 0, h, 0)
-
-    def _scale_idx(b, h, j, ptab_ref, lens_ref):
-        return (jnp.minimum(ptab_ref[b, j], npages - 1), 0, h)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda b, h, j, pt, ln: (b, h, 0, 0)),
-        pl.BlockSpec((1, page, 1, hd), _page_idx),
-        pl.BlockSpec((1, page, 1, hd), _page_idx),
-    ]
-    args = [ptab, lens, q, kp, vp]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, page, 1), _scale_idx),
-                     pl.BlockSpec((1, page, 1), _scale_idx)]
-        args += [ks, vs]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, kvH, pps),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, j, pt, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_decode_kernel, page=page, npages=npages,
-                          scale=scale, quantized=quantized),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kvH, G, hd), q.dtype),
-        interpret=interpret,
-    )(*args)
-
-
-# ---------------------------------------------------------------------------
-# Ragged paged flash (serving): attention for a flat pack of T query tokens
-# from arbitrary slots — the kernel-level half of the engine's single ragged
-# program.  Each pack token carries its own slot index and visible length, so
-# prefill-chunk tokens and decode tokens run through the same grid; the slot
-# index rides in as scalar prefetch and resolves the per-token block-table
-# row in the BlockSpec index_map (a double indirection: token -> slot ->
-# page -> pool row), before each grid step's DMA.  Grid = (T, kvH, pps),
-# pages innermost (sequential online-softmax state in VMEM).
+# Paged flash attention (serving): query tokens from arbitrary slots attend
+# over a block-table-indexed KV pool.  The kernel-level half of the engine's
+# single ragged program: each query row carries its own slot index and
+# visible length, so prefill-chunk tokens and decode tokens run through the
+# same grid.  The slot index and block table ride in as scalar prefetch and
+# resolve the row's pool page in the BlockSpec index_map (a double
+# indirection: row -> slot -> page -> pool row) before each grid step's DMA;
+# the kernel body only ever sees a dense (page, hd) tile of one KV head.
+# Grid = (rows, kvH, pps), pages innermost (sequential online-softmax state
+# in VMEM).
+#
+# Pool layout is HEAD-MAJOR: kp/vp (n_pages, kvH, page, hd), int8 scale
+# pools ks/vs (n_pages, kvH, 1, page).  Mosaic requires the last two dims of
+# every block to be (8k, 128k)-divisible or whole, so a page tile must be a
+# (page, hd) slab of one head; a page-major pool would need a (page, 1, hd)
+# block with a size-1 second-minor dim, which Mosaic refuses.
 
 
 def _ragged_decode_kernel(slot_ref, lens_ref, ptab_ref, q_ref, k_ref, v_ref,
-                          *rest, page: int, npages: int, scale: float,
-                          quantized: bool):
+                          *rest, page: int, scale: float, quantized: bool):
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -238,7 +116,7 @@ def _ragged_decode_kernel(slot_ref, lens_ref, ptab_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # visible entries of this page for this token: positions 0..lens-1 are
+    # visible entries of this page for this row: positions 0..lens-1 are
     # contiguous per slot, so the causal mask is just a length cutoff —
     # intra-pack keys written at positions beyond this token stay invisible
     n_valid = lens_ref[t] - ji * page
@@ -246,12 +124,11 @@ def _ragged_decode_kernel(slot_ref, lens_ref, ptab_ref, q_ref, k_ref, v_ref,
     @pl.when(n_valid > 0)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (page, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (page, hd)
-        if quantized:  # fused dequant: int8 page tile × its scale row
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32)  # (page, hd)
+        v = v_ref[0, 0].astype(jnp.float32)  # (page, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, page)
+        if quantized:  # fused dequant: a per-entry scale is a column scale
+            s = s * ks_ref[0, 0]  # (1, page)
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(cols < n_valid, s, NEG_INF)
 
@@ -261,7 +138,8 @@ def _ragged_decode_kernel(slot_ref, lens_ref, ptab_ref, q_ref, k_ref, v_ref,
         p = jnp.where(cols < n_valid, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(p, v)
+        pv = p * vs_ref[0, 0] if quantized else p
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(pv, v)
         m_ref[...] = m_new
 
     @pl.when(ji == pl.num_programs(2) - 1)
@@ -277,8 +155,9 @@ def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None, *,
     q: (T, kvH, G, hd) — T pack tokens from arbitrary slots; slot: (T,)
     int32 per-token slot index; lens: (T,) int32 visible entries for each
     token (``q_pos + 1``; 0 = invalid token, output is zeros);
-    kp, vp: (n_pages, page, kvH, hd); ptab: (B, pps) int32 block table.
-    Returns (T, kvH, G, hd).  Full (non-windowed) causal layers only.
+    kp, vp: (n_pages, kvH, page, hd); ptab: (B, pps) int32 block table
+    (entries >= n_pages = unmapped).  Returns (T, kvH, G, hd).  Full
+    (non-windowed) causal layers only.
 
     Refcounted prefix-shared pages (serve.engine) require NO kernel change:
     every K/V tile is fetched through the token -> slot -> page indirection
@@ -286,13 +165,14 @@ def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None, *,
     page read the same bytes, and copy-on-write happens before the step in
     the allocator (a ``kernels.ops.copy_pages`` call), never in here.
 
-    int8 quantized pools require only the scale-row side channel: pass
-    ``ks``/``vs`` ((n_pages, page, kvH) float32) and each page tile is
-    dequantized in VMEM right after its DMA — aliased (prefix-shared) pages
-    alias their scale rows through the same indirection, so sharing, COW,
-    and quantization compose without further machinery.
+    int8 quantized pools require only the scale side channel: pass
+    ``ks``/``vs`` ((n_pages, kvH, 1, page) float32, one scale per pool entry
+    per KV head).  The page tile is DMA'd as int8 and its scales applied in
+    VMEM — K's to the (G, page) scores, V's to the probabilities — so the
+    fp32 online-softmax accumulate is unchanged while HBM traffic per page
+    is the int8 bytes plus one scale row, never a dequantized copy.
     """
-    if interpret is None:  # resolve outside the jit boundary (see above)
+    if interpret is None:  # resolve outside the jit boundary (static key)
         interpret = default_interpret()
     return _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs,
                                interpret=interpret)
@@ -301,36 +181,28 @@ def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None, *,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs, *, interpret):
     T, kvH, G, hd = q.shape
-    npages, page = kp.shape[0], kp.shape[1]
+    npages, page = kp.shape[0], kp.shape[2]
     pps = ptab.shape[1]
-    scale = hd ** -0.5
     quantized = ks is not None
 
     def _page_idx(t, h, j, slot_ref, lens_ref, ptab_ref):
-        # token -> slot -> page -> pool row; unmapped sentinel pages clamp
+        # row -> slot -> page -> pool row; unmapped sentinel pages clamp
         # to a real row whose entries are dead via the lens cutoff
-        return (jnp.minimum(ptab_ref[slot_ref[t], j], npages - 1), 0, h, 0)
+        return (jnp.minimum(ptab_ref[slot_ref[t], j], npages - 1), h, 0, 0)
 
-    def _scale_idx(t, h, j, slot_ref, lens_ref, ptab_ref):
-        return (jnp.minimum(ptab_ref[slot_ref[t], j], npages - 1), 0, h)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda t, h, j, sl, ln, pt: (t, h, 0, 0)),
-        pl.BlockSpec((1, page, 1, hd), _page_idx),
-        pl.BlockSpec((1, page, 1, hd), _page_idx),
-    ]
+    row = pl.BlockSpec((1, 1, G, hd), lambda t, h, j, *_: (t, h, 0, 0))
+    in_specs = [row, pl.BlockSpec((1, 1, page, hd), _page_idx),
+                pl.BlockSpec((1, 1, page, hd), _page_idx)]
     args = [slot, lens, ptab, q, kp, vp]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page, 1), _scale_idx),
-                     pl.BlockSpec((1, page, 1), _scale_idx)]
+        in_specs += [pl.BlockSpec((1, 1, 1, page), _page_idx)] * 2
         args += [ks, vs]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(T, kvH, pps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda t, h, j, sl, ln, pt: (t, h, 0, 0)),
+        out_specs=row,
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -338,8 +210,8 @@ def _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs, *, interpret):
         ],
     )
     return pl.pallas_call(
-        functools.partial(_ragged_decode_kernel, page=page, npages=npages,
-                          scale=scale, quantized=quantized),
+        functools.partial(_ragged_decode_kernel, page=page, scale=hd ** -0.5,
+                          quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, kvH, G, hd), q.dtype),
         interpret=interpret,

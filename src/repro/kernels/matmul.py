@@ -51,8 +51,7 @@ def matmul(a, b, *, block=(256, 256, 256), accum="vmem", interpret=None,
     """C = A·B with explicit VMEM tiling.  A: (M,K), B: (K,N).
 
     ``interpret=None`` resolves through ``kernels.ops.default_interpret()``:
-    compiled on TPU backends, interpret mode elsewhere (resolved OUTSIDE the
-    jit boundary so a REPRO_PALLAS_INTERPRET change retraces)."""
+    compiled on TPU backends, interpret mode elsewhere."""
     if interpret is None:
         interpret = default_interpret()
     return _matmul(a, b, block=block, accum=accum, interpret=interpret,

@@ -1,18 +1,20 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Every Pallas entry point resolves its ``interpret`` flag through
-``default_interpret()``: interpret mode off-TPU (this container is CPU-only;
-the kernels target TPU), compiled for real on a TPU backend, overridable via
-``REPRO_PALLAS_INTERPRET`` for forcing either mode.
+``default_interpret()``: interpret mode off-TPU, compiled for real on a TPU
+backend.
 
 This module also owns the serving cache's int8 machinery: the paged KV pool
 can store K/V pages as symmetric int8 with one float32 scale per pool entry
 per KV head (``quantize_kv`` / ``dequantize_kv``, absmax over the head dim),
-written by the fused quantize-on-write scatter (``kv_scatter_quantized``)
-and read back by the fused-dequant paths of ``paged_flash_decode`` /
-``ragged_paged_flash`` (dequant in VMEM right after the page DMA).
+written by the serving steps' quantize-on-write KV scatter
+(``models.layers.attention``) and read back by the fused-dequant path of
+``ragged_paged_flash`` (scales applied in VMEM right after the page DMA).
 ``copy_pages`` carries the scale rows along with their pages so
 copy-on-write stays correct for quantized pools.
+
+Paged pools are HEAD-MAJOR (see ``kernels.flash_attention``): values
+``(n_pages, kvH, page, hd)``, int8 scales ``(n_pages, kvH, 1, page)``.
 """
 from __future__ import annotations
 
@@ -37,27 +39,16 @@ def flash_attention(q, k, v, *, bq=128, bk=128, window=None):
                                interpret=default_interpret())
 
 
-def paged_flash_decode(q, kp, vp, ptab, lens, ks=None, vs=None):
-    """Serving decode attention over a block-table-paged KV pool.
-    q: (B,kvH,G,hd); kp/vp: (n_pages,page,kvH,hd) -> (B,kvH,G,hd).
-
-    int8 pools ride with per-entry-per-head scale pools ``ks``/``vs``
-    ((n_pages, page, kvH) float32): the kernel dequantizes each page tile in
-    VMEM right after its DMA, inside the same online-softmax loop."""
-    return _fa.paged_flash_decode(q, kp, vp, ptab, lens, ks=ks, vs=vs,
-                                  interpret=default_interpret())
-
-
 def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None):
     """Ragged-pack serving attention over a block-table-paged KV pool.
-    q: (T,kvH,G,hd); slot/lens: (T,); kp/vp: (n_pages,page,kvH,hd)
+    q: (T,kvH,G,hd); slot/lens: (T,); kp/vp: (n_pages,kvH,page,hd)
     -> (T,kvH,G,hd).
 
     Prefix-shared pages need no kernel support: the kernel resolves
     token -> slot -> page through ``ptab`` per grid step, so two slots whose
     block-table rows point at the same pool page simply DMA the same tile —
     sharing and copy-on-write are entirely a host-side allocator concern.
-    int8 pools pass scale pools ``ks``/``vs`` ((n_pages, page, kvH) f32);
+    int8 pools pass scale pools ``ks``/``vs`` ((n_pages,kvH,1,page) f32);
     dequant is fused into the kernel's inner loop, so the HBM traffic per
     page is the int8 bytes plus one scale row — not a dequantized copy."""
     return _fa.ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=ks, vs=vs,
@@ -89,39 +80,23 @@ def dequantize_kv(q, s, dtype=jnp.float32):
     return (q.astype(jnp.float32) * s[..., None].astype(jnp.float32)).astype(dtype)
 
 
-def kv_scatter_quantized(pool, scales, rows, page, off):
-    """Fused quantize-on-write KV scatter for int8 paged pools.
+def copy_pages(pool, src, dst):
+    """Copy-on-write page copy: ``pool[..., dst[i], :, :, :] = pool[..., src[i], :, :, :]``.
 
-    Quantizes ``rows`` ((..., kvH, hd), any float dtype) and scatters values
-    into ``pool[page, off]`` (int8) and their scales into
-    ``scales[page, off]`` (f32, (n_pages, page_size, kvH)) in one traced
-    program — the write-side half of the quantized-pool lifecycle (the
-    read side is the fused dequant in the flash kernels).  OOB sentinel
-    pages drop both writes, exactly like the unquantized scatter."""
-    q, s = quantize_kv(rows)
-    pool = pool.at[page, off].set(q, mode="drop")
-    scales = scales.at[page, off].set(s, mode="drop")
-    return pool, scales
-
-
-def copy_pages(pool, src, dst, axis=None):
-    """Copy-on-write page copy: ``pool[..., dst[i], :, ...] = pool[..., src[i], ...]``.
-
-    pool: (..., n_pages, page, kvH, hd) KV pool (``axis=None`` resolves the
-    page axis as ``ndim - 4``) or a (..., n_pages, page, kvH) scale pool
-    (pass ``axis = ndim - 3``); an optional leading layer axis from scanned
-    stages rides along in each slice.  Scale rows MUST travel with their
-    pages — a COW'd int8 page dequantized against another page's scales
-    would silently corrupt the copied prefix.  src/dst: (K,) int32 with a
-    FIXED K (the engine pads unused pairs with the ``n_pages`` sentinel), so
-    the op stays one traced program.  Implemented as K unrolled
-    dynamic-slice updates rather than one batched scatter: with the pool
-    donated, each update is an in-place page-sized memcpy (the same pattern
-    as a KV-cache write), whereas a scatter with leading batch-dim slices
-    makes XLA CPU rewrite the whole pool (~2 model steps per call when
-    measured).  Sentinel pairs clamp to a self-copy of the last page — a
-    byte-identical no-op."""
-    ax = pool.ndim - 4 if axis is None else axis
+    pool: (..., n_pages, kvH, page, hd) KV pool or (..., n_pages, kvH, 1,
+    page) scale pool — the page axis is ``ndim - 4`` for both, and an
+    optional leading layer axis from scanned stages rides along in each
+    slice.  Scale rows MUST travel with their pages — a COW'd int8 page
+    dequantized against another page's scales would silently corrupt the
+    copied prefix.  src/dst: (K,) int32 with a FIXED K (the engine pads
+    unused pairs with the ``n_pages`` sentinel), so the op stays one traced
+    program.  Implemented as K unrolled dynamic-slice updates rather than
+    one batched scatter: with the pool donated, each update is an in-place
+    page-sized memcpy (the same pattern as a KV-cache write), whereas a
+    scatter with leading batch-dim slices makes XLA CPU rewrite the whole
+    pool (~2 model steps per call when measured).  Sentinel pairs clamp to
+    a self-copy of the last page — a byte-identical no-op."""
+    ax = pool.ndim - 4
     n = pool.shape[ax]
     for i in range(src.shape[0]):
         v = jax.lax.dynamic_index_in_dim(pool, jnp.minimum(src[i], n - 1),

@@ -22,8 +22,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
     """x: (..., D); scale: (D,).
 
     ``interpret=None`` resolves through ``kernels.ops.default_interpret()``:
-    compiled on TPU backends, interpret mode elsewhere (resolved OUTSIDE the
-    jit boundary so a REPRO_PALLAS_INTERPRET change retraces)."""
+    compiled on TPU backends, interpret mode elsewhere."""
     if interpret is None:
         interpret = default_interpret()
     return _rmsnorm(x, scale, eps=eps, block_rows=block_rows,

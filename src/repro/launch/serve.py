@@ -5,14 +5,19 @@ engine (``--engine chunked`` runs the PR 1 two-phase paged engine,
 slo); with ``slo``, ``--interactive-every N`` marks every Nth request
 priority 1 so the policy has two classes to separate.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma3-4b --requests 12
+The model is built at its published width unless ``--smoke`` asks for the
+small preset (what CPU runs use):
+
+  PYTHONPATH=src python -m repro.launch.serve --arch gemma3-4b --smoke --requests 12
 """
 import argparse
+import os
 
 import jax
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config, skip_reason
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve.engine import ServeEngine
 from repro.serve.reference import ReferenceEngine
@@ -21,6 +26,9 @@ from repro.serve.reference import ReferenceEngine
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="qwen2-1.5b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's small smoke preset instead of its "
+                         "published width")
     ap.add_argument("--engine", choices=("ragged", "chunked", "reference"),
                     default="ragged")
     ap.add_argument("--requests", type=int, default=8)
@@ -61,7 +69,11 @@ def main(argv=None):
 
     if skip_reason(args.arch, "decode_32k"):
         raise SystemExit(f"{args.arch}: {skip_reason(args.arch, 'decode_32k')}")
-    cfg = get_config(args.arch, smoke=True)
+    # before the backend starts: libtpu otherwise logs to a fixed directory
+    # outside the checkout
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke)
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     cache_len = max(128, args.prompt_len + args.max_tokens)
     if args.engine == "reference":

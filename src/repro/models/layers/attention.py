@@ -230,9 +230,11 @@ def prefill_cache(params, cfg: AttnCfg, cache, x, positions):
 # The pool's MEMORY REPRESENTATION is configurable (``kv_dtype``): float32 /
 # bfloat16 pools store KV verbatim; int8 pools store symmetric int8 values
 # plus one float32 scale per pool entry per KV head (``ks``/``vs``,
-# (n_pages, page, kvH)).  The lifecycle is write-quantize -> paged
+# (n_pages, kvH, 1, page)).  Pools are head-major — values (n_pages, kvH,
+# page, hd) — so the Pallas kernel can DMA one head's (page, hd) tile (see
+# ``kernels.flash_attention``).  The lifecycle is write-quantize -> paged
 # read-dequant -> COW-with-scales: K/V rows are quantized ONCE as they are
-# scattered into the pool (``kernels.ops.kv_scatter_quantized``), every
+# scattered into the pool (``_scatter_paged_kv``), every
 # reader (prefill chunks, decode ticks, prefix hits, the Pallas kernels)
 # dequantizes the same representation, and copy-on-write copies a page's
 # scale row with its values.  Quantizing at write time means a page is
@@ -279,15 +281,15 @@ def init_paged_cache(cfg: AttnCfg, batch: int, cache_len: int, dtype, *,
     kvd = kv_cache_dtype(kv_dtype, dtype)
     pps = -(-cache_len // page_size)  # block-table width (pages per slot)
     cache = {
-        "kp": jnp.zeros((n_pages, page_size, kvH, hd), kvd),
-        "vp": jnp.zeros((n_pages, page_size, kvH, hd), kvd),
+        "kp": jnp.zeros((n_pages, kvH, page_size, hd), kvd),
+        "vp": jnp.zeros((n_pages, kvH, page_size, hd), kvd),
         "ptab": jnp.full((batch, pps), n_pages, jnp.int32),
         "kpos": jnp.full((batch, pps * page_size), -1, jnp.int32),
         "slen": jnp.zeros((batch,), jnp.int32),
     }
     if kvd == jnp.int8:
-        cache["ks"] = jnp.zeros((n_pages, page_size, kvH), jnp.float32)
-        cache["vs"] = jnp.zeros((n_pages, page_size, kvH), jnp.float32)
+        cache["ks"] = jnp.zeros((n_pages, kvH, 1, page_size), jnp.float32)
+        cache["vs"] = jnp.zeros((n_pages, kvH, 1, page_size), jnp.float32)
     return cache
 
 
@@ -306,39 +308,42 @@ def _paged_masked_attn(q, k, v, kpos, q_pos, window):
 
 
 def _scatter_paged_kv(cache, k_new, v_new, page, off):
-    """Scatter new K/V rows into the pool at (page, off) — the single write
-    path shared by the two-phase and ragged steps.  int8 pools quantize on
-    write (values + scale rows, ``kernels.ops.kv_scatter_quantized``); OOB
-    sentinel pages drop the write either way.  Mutates the caller's cache
-    dict (callers own a fresh copy)."""
+    """Scatter new K/V rows into the head-major pools at ``[page, :, off]``
+    — the single write path shared by the two-phase and ragged steps.
+    k_new/v_new: (..., kvH, hd) with ``page``/``off`` of shape (...).  int8
+    pools quantize on write: values into ``kp``/``vp`` and their scales into
+    ``ks``/``vs`` at ``[page, :, 0, off]``; OOB sentinel pages drop the
+    write either way.  Mutates the caller's cache dict (callers own a fresh
+    copy)."""
     from repro.kernels import ops as kops
 
-    if "ks" in cache:  # int8 pool: fused quantize-on-write
-        cache["kp"], cache["ks"] = kops.kv_scatter_quantized(
-            cache["kp"], cache["ks"], k_new, page, off)
-        cache["vp"], cache["vs"] = kops.kv_scatter_quantized(
-            cache["vp"], cache["vs"], v_new, page, off)
-    else:
-        cache["kp"] = cache["kp"].at[page, off].set(
-            k_new.astype(cache["kp"].dtype), mode="drop")
-        cache["vp"] = cache["vp"].at[page, off].set(
-            v_new.astype(cache["vp"].dtype), mode="drop")
+    for name, rows in (("k", k_new), ("v", v_new)):
+        pool = cache[name + "p"]
+        if name + "s" in cache:  # int8 pool: fused quantize-on-write
+            rows, s = kops.quantize_kv(rows)
+            cache[name + "s"] = cache[name + "s"].at[page, :, 0, off].set(
+                s, mode="drop")
+        cache[name + "p"] = pool.at[page, :, off].set(
+            rows.astype(pool.dtype), mode="drop")
 
 
 def _gather_paged_kv(cache, dtype):
     """Gather the whole block-table context from the pool, dequantizing int8
-    pools against their per-entry scale rows (the jnp oracle of the fused
-    kernel path).  Returns (k, v) of shape (B, pps, P, kvH, hd) in ``dtype``.
+    pools against their per-entry scales (the jnp oracle of the fused kernel
+    path).  Returns (k, v) of shape (B, pps * page, kvH, hd) in ``dtype``.
     """
-    k = jnp.take(cache["kp"], cache["ptab"], axis=0, mode="clip")
-    v = jnp.take(cache["vp"], cache["ptab"], axis=0, mode="clip")
-    if "ks" in cache:
-        from repro.kernels import ops as kops
+    from repro.kernels import ops as kops
 
-        ks = jnp.take(cache["ks"], cache["ptab"], axis=0, mode="clip")
-        vs = jnp.take(cache["vs"], cache["ptab"], axis=0, mode="clip")
-        return kops.dequantize_kv(k, ks, dtype), kops.dequantize_kv(v, vs, dtype)
-    return k.astype(dtype), v.astype(dtype)
+    def gather(name):
+        x = jnp.take(cache[name + "p"], cache["ptab"], axis=0, mode="clip")
+        if name + "s" in cache:  # (B, pps, kvH, 1, P) -> (B, pps, kvH, P)
+            s = jnp.take(cache[name + "s"], cache["ptab"], axis=0,
+                         mode="clip")[..., 0, :]
+            x = kops.dequantize_kv(x, s, dtype)
+        B, pps, kvH, P, hd = x.shape  # head-major pages -> token-major
+        return jnp.swapaxes(x, 2, 3).reshape(B, pps * P, kvH, hd).astype(dtype)
+
+    return gather("k"), gather("v")
 
 
 def paged_attention_step(params, cfg: AttnCfg, x, cache, q_pos, valid, *,
@@ -361,7 +366,7 @@ def paged_attention_step(params, cfg: AttnCfg, x, cache, q_pos, valid, *,
     paged = "kp" in cache
     cache = dict(cache)
     if paged:
-        P = cache["kp"].shape[1]
+        P = cache["kp"].shape[2]
         n_pages = cache["kp"].shape[0]
         pps = cache["ptab"].shape[-1]
         page_slot = jnp.clip(q_pos // P, 0, pps - 1)
@@ -389,17 +394,16 @@ def paged_attention_step(params, cfg: AttnCfg, x, cache, q_pos, valid, *,
     if paged and flash_decode and C == 1:
         # TP entry point: shard_maps the Pallas kernel over the KV-head axis
         # under the serving mesh, plain kernel call otherwise
-        from repro.serve.decode_attention import tp_paged_flash_decode
+        # under the serving mesh, plain kernel call otherwise; one query row
+        # per slot is the ragged kernel with slot = arange(B)
+        from repro.serve.decode_attention import tp_ragged_paged_flash
 
-        o = tp_paged_flash_decode(q[:, 0], cache["kp"], cache["vp"],
-                                  cache["ptab"], cache["slen"],
-                                  ks=cache.get("ks"),
+        o = tp_ragged_paged_flash(q[:, 0], cache["kp"], cache["vp"],
+                                  cache["ptab"], jnp.arange(B, dtype=jnp.int32),
+                                  cache["slen"], ks=cache.get("ks"),
                                   vs=cache.get("vs"))[:, None]
     elif paged:
         k, v = _gather_paged_kv(cache, q.dtype)
-        kvH, hd = cfg.num_kv_heads, cfg.head_dim
-        k = k.reshape(B, T, kvH, hd)
-        v = v.reshape(B, T, kvH, hd)
         o = _paged_masked_attn(q, k, v, cache["kpos"], q_pos, cfg.window)
     else:
         o = _paged_masked_attn(q, cache["k"], cache["v"], cache["kpos"],
@@ -430,7 +434,7 @@ def ragged_attention_step(params, cfg: AttnCfg, x, cache, slot, q_pos, valid,
     cache = dict(cache)
     B = cache["slen"].shape[0]
     if paged:
-        P = cache["kp"].shape[1]
+        P = cache["kp"].shape[2]
         n_pages = cache["kp"].shape[0]
         pps = cache["ptab"].shape[-1]
         page_slot = jnp.clip(q_pos // P, 0, pps - 1)
@@ -471,9 +475,6 @@ def ragged_attention_step(params, cfg: AttnCfg, x, cache, slot, q_pos, valid,
 
     if paged:
         k_all, v_all = _gather_paged_kv(cache, q.dtype)
-        kvH, hd = cfg.num_kv_heads, cfg.head_dim
-        k_all = k_all.reshape(B, Tc, kvH, hd)
-        v_all = v_all.reshape(B, Tc, kvH, hd)
     else:
         k_all, v_all = cache["k"], cache["v"]
     # gather each token's slot context and run T single-query attentions:

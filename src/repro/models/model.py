@@ -303,38 +303,26 @@ def copy_kv_pages(cfg: ModelCfg, state, src, dst) -> Dict:
     from repro.kernels import ops as kops
 
     def leaf_copy(path, leaf):
-        name = None
-        for p in reversed(path):
-            if isinstance(p, jax.tree_util.DictKey):
-                name = p.key
-                break
-        if name in ("kp", "vp"):
-            return kops.copy_pages(leaf, src, dst)
-        if name in ("ks", "vs"):
-            # int8 pools: a page's scale row travels with its values — a
-            # COW'd page dequantized against the wrong scales would corrupt
-            # the shared prefix (scale pools have no trailing head dim, so
-            # the page axis sits at ndim-3)
-            return kops.copy_pages(leaf, src, dst, axis=leaf.ndim - 3)
-        return leaf
+        # int8 pools: a page's scale row travels with its values — a COW'd
+        # page dequantized against the wrong scales would corrupt the
+        # shared prefix
+        return leaf if _paged_leaf_axis(path, leaf) is None else \
+            kops.copy_pages(leaf, src, dst)
 
     return jax.tree_util.tree_map_with_path(leaf_copy, state)
 
 
 def _paged_leaf_axis(path, leaf) -> Optional[int]:
-    """Page axis of a paged-pool leaf (kp/vp values at ndim-4, ks/vs scale
-    rows at ndim-3 — leading layer dims of scanned stages ride along), or
-    ``None`` for per-slot state with no shareable pages."""
+    """Page axis of a paged-pool leaf (kp/vp values and ks/vs scale rows
+    are all head-major 4-D pools, so it is ndim-4 — leading layer dims of
+    scanned stages ride along), or ``None`` for per-slot state with no
+    shareable pages."""
     name = None
     for p in reversed(path):
         if isinstance(p, jax.tree_util.DictKey):
             name = p.key
             break
-    if name in ("kp", "vp"):
-        return leaf.ndim - 4
-    if name in ("ks", "vs"):
-        return leaf.ndim - 3
-    return None
+    return leaf.ndim - 4 if name in ("kp", "vp", "ks", "vs") else None
 
 
 def gather_kv_page(cfg: ModelCfg, state, page) -> Dict[str, jax.Array]:

@@ -89,15 +89,20 @@ def block_fwd(params, cfg: ModelCfg, blk: BlockCfg, x, *, positions=None, enc=No
 
 
 def init_stage(key, cfg: ModelCfg, stage: Stage):
-    reps = []
-    for r in range(stage.repeats):
-        kr = jax.random.fold_in(key, r)
-        reps.append([init_block(jax.random.fold_in(kr, i), cfg, blk)
-                     for i, blk in enumerate(stage.pattern)])
+    """Per-block params, stacked over ``stage.repeats``.  Block ``i`` of
+    repeat ``r`` draws from ``fold_in(fold_in(key, r), i)``; the repeats are
+    drawn under ``vmap`` over their keys (bit-identical to a loop), so a
+    jitted init holds one copy of each block's random ops instead of one
+    per layer — a published-width init then compiles in seconds."""
     if stage.repeats == 1:
-        return reps[0]
-    return [jax.tree.map(lambda *xs: jnp.stack(xs), *[reps[r][i] for r in range(stage.repeats)])
-            for i in range(len(stage.pattern))]
+        kr = jax.random.fold_in(key, 0)
+        return [init_block(jax.random.fold_in(kr, i), cfg, blk)
+                for i, blk in enumerate(stage.pattern)]
+    keys = jax.vmap(lambda r: jax.random.fold_in(key, r))(
+        jnp.arange(stage.repeats))
+    return [jax.vmap(lambda kr, i=i, blk=blk: init_block(
+                jax.random.fold_in(kr, i), cfg, blk))(keys)
+            for i, blk in enumerate(stage.pattern)]
 
 
 @jax.custom_jvp

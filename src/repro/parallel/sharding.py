@@ -20,24 +20,11 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checking off.
-
-    ``jax.shard_map`` (with ``check_vma``) only exists on newer JAX; this
-    environment's 0.4.x line ships ``jax.experimental.shard_map.shard_map``
-    (with ``check_rep``).  Every call site in the repo wants the same thing
-    — per-shard execution with no replication verification — so route them
-    all through one shim instead of version-guessing at each site."""
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        try:
-            return top(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_vma=False)
-        except TypeError:  # newer-but-different keyword surface
-            return top(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off — what every call
+    site in the repo wants: per-shard execution, no verification that
+    replicated outputs agree across shards."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -12,14 +12,14 @@ One of the two decode-attention paths under ``serve/``:
   32k–512k contexts serveable at all (an all-gathered KV would be GBs per
   layer per step).
 - **The paged per-slot path** — ``models.layers.attention
-  .paged_attention_step`` (jnp gather over block tables) and
-  ``kernels.flash_attention.paged_flash_decode`` (Pallas, block table as
-  scalar prefetch), driven by ``serve.engine.ServeEngine``.  Use that for
-  mixed-length continuous batching; use this one when the KV of a single
-  sequence outgrows one device.
+  .paged_attention_step`` / ``ragged_attention_step`` (jnp gather over
+  block tables) and ``kernels.flash_attention.ragged_paged_flash`` (Pallas,
+  block table as scalar prefetch), driven by ``serve.engine.ServeEngine``.
+  Use that for mixed-length continuous batching; use this one when the KV
+  of a single sequence outgrows one device.
 
-This module also owns the tensor-parallel entry points of the paged flash
-kernels (``tp_ragged_paged_flash`` / ``tp_paged_flash_decode``): under an
+This module also owns the tensor-parallel entry point of the paged flash
+kernel (``tp_ragged_paged_flash``): under an
 engine mesh the paged KV pools are sharded over the KV-head axis
 (serve_step.STATE_AXES "act_kv_heads"), and since GSPMD cannot partition a
 ``pallas_call``, the kernels run inside an explicit ``shard_map`` over that
@@ -117,50 +117,24 @@ def _head_tp(kvH: int):
 
 def tp_ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None):
     """``kernels.ops.ragged_paged_flash`` under the engine mesh: shard_map
-    over the KV-head axis of q and the paged pools (values + int8 scales);
-    ptab/slot/lens replicate.  Falls back to the plain kernel call with no
-    mesh, no "act_kv_heads" rule, or an indivisible head count."""
+    over the KV-head axis of q and the head-major paged pools (values and
+    int8 scales share the spec); ptab/slot/lens replicate.  Falls back to
+    the plain kernel call with no mesh, no "act_kv_heads" rule, or an
+    indivisible head count."""
     from repro.kernels import ops as kops
 
     mesh, h = _head_tp(q.shape[1])
     if mesh is None:
         return kops.ragged_paged_flash(q, kp, vp, ptab, slot, lens,
                                        ks=ks, vs=vs)
-    qspec, pspec, sspec = P(None, h, None, None), P(None, None, h, None), \
-        P(None, None, h)
-    if ks is None:
-        return shard_map(
-            lambda q, kp, vp, ptab, slot, lens: kops.ragged_paged_flash(
-                q, kp, vp, ptab, slot, lens),
-            mesh=mesh, in_specs=(qspec, pspec, pspec, P(), P(), P()),
-            out_specs=qspec)(q, kp, vp, ptab, slot, lens)
+    heads = P(None, h, None, None)  # axis 1 of q and of every pool
+    pools = (kp, vp) if ks is None else (kp, vp, ks, vs)
+
+    def local(q, ptab, slot, lens, *pools):
+        return kops.ragged_paged_flash(q, pools[0], pools[1], ptab, slot,
+                                       lens, *pools[2:])
+
     return shard_map(
-        lambda q, kp, vp, ptab, slot, lens, ks, vs: kops.ragged_paged_flash(
-            q, kp, vp, ptab, slot, lens, ks=ks, vs=vs),
-        mesh=mesh,
-        in_specs=(qspec, pspec, pspec, P(), P(), P(), sspec, sspec),
-        out_specs=qspec)(q, kp, vp, ptab, slot, lens, ks, vs)
-
-
-def tp_paged_flash_decode(q, kp, vp, ptab, lens, ks=None, vs=None):
-    """``kernels.ops.paged_flash_decode`` under the engine mesh (lock-step
-    C==1 decode shape, q: (B,kvH,G,hd)); same sharding contract as
-    ``tp_ragged_paged_flash``."""
-    from repro.kernels import ops as kops
-
-    mesh, h = _head_tp(q.shape[1])
-    if mesh is None:
-        return kops.paged_flash_decode(q, kp, vp, ptab, lens, ks=ks, vs=vs)
-    qspec, pspec, sspec = P(None, h, None, None), P(None, None, h, None), \
-        P(None, None, h)
-    if ks is None:
-        return shard_map(
-            lambda q, kp, vp, ptab, lens: kops.paged_flash_decode(
-                q, kp, vp, ptab, lens),
-            mesh=mesh, in_specs=(qspec, pspec, pspec, P(), P()),
-            out_specs=qspec)(q, kp, vp, ptab, lens)
-    return shard_map(
-        lambda q, kp, vp, ptab, lens, ks, vs: kops.paged_flash_decode(
-            q, kp, vp, ptab, lens, ks=ks, vs=vs),
-        mesh=mesh, in_specs=(qspec, pspec, pspec, P(), P(), sspec, sspec),
-        out_specs=qspec)(q, kp, vp, ptab, lens, ks, vs)
+        local, mesh=mesh,
+        in_specs=(heads, P(), P(), P()) + (heads,) * len(pools),
+        out_specs=heads)(q, ptab, slot, lens, *pools)

@@ -1424,8 +1424,13 @@ class ServeEngine:
                 self._state = jax.device_put(self._state, ns)
                 self.params = jax.device_put(
                     self.params, NamedSharding(self.mesh, P()))
-            # the reset template must not alias the (donated) live state
-            self._template = jax.tree.map(jax.numpy.copy, self._state)
+            # the reset template must not alias the (donated) live state;
+            # it holds only per-slot leaves, since the reset never reads the
+            # shared pools from it (a copy would double the pool's bytes)
+            self._template = {"layers": [
+                [{n: jax.numpy.copy(x) for n, x in blk.items()
+                  if n not in ("kp", "vp", "ks", "vs")} for blk in stage]
+                for stage in self._state["layers"]]}
 
     def tick(self) -> Dict[int, List[int]]:
         """One scheduling tick: admit from the queue, pack, run one program

@@ -157,13 +157,14 @@ STATE_AXES: Dict[str, tuple] = {
     "v": ("act_kv_batch", "act_kv_seq", "act_kv_heads", None),
     "k_pos": ("act_kv_seq",),
     "pos": (),
-    # paged KV (per-slot engine): page pools shard over KV heads; block
-    # tables / positions are per-slot and follow the batch axis.  int8
-    # pools add per-entry scale pools (ks/vs) that shard with their pages.
-    "kp": (None, None, "act_kv_heads", None),
-    "vp": (None, None, "act_kv_heads", None),
-    "ks": (None, None, "act_kv_heads"),
-    "vs": (None, None, "act_kv_heads"),
+    # paged KV (per-slot engine): head-major page pools (n_pages, kvH,
+    # page, hd) shard over KV heads; block tables / positions are per-slot
+    # and follow the batch axis.  int8 pools add per-entry scale pools
+    # (ks/vs, (n_pages, kvH, 1, page)) that shard with their pages.
+    "kp": (None, "act_kv_heads", None, None),
+    "vp": (None, "act_kv_heads", None, None),
+    "ks": (None, "act_kv_heads", None, None),
+    "vs": (None, "act_kv_heads", None, None),
     "ptab": ("act_kv_batch", None),
     "kpos": ("act_kv_batch", None),
     "slen": ("act_kv_batch",),

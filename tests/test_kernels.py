@@ -98,12 +98,20 @@ def test_flash_causality(sq, hd, g):
                                rtol=1e-5, atol=1e-5)
 
 
+def _gather_rows(pool, ptab):
+    """Head-major pool (n_pages, kvH, page, hd) -> per-slot token rows
+    (B, pps * page, kvH, hd) through the block table."""
+    x = jnp.take(pool, ptab, axis=0, mode="clip")  # (B, pps, kvH, page, hd)
+    B, pps, kvH, page, hd = x.shape
+    return jnp.swapaxes(x, 2, 3).reshape(B, pps * page, kvH, hd)
+
+
 def _paged_decode_ref(q, kp, vp, ptab, lens):
     """jnp oracle: gather the block table, mask by fill count, softmax."""
     B, kvH, G, hd = q.shape
-    pps, page = ptab.shape[1], kp.shape[1]
-    k = jnp.take(kp, ptab, axis=0, mode="clip").reshape(B, pps * page, kvH, hd)
-    v = jnp.take(vp, ptab, axis=0, mode="clip").reshape(B, pps * page, kvH, hd)
+    pps, page = ptab.shape[1], kp.shape[2]
+    k = _gather_rows(kp, ptab)
+    v = _gather_rows(vp, ptab)
     s = jnp.einsum("bkgd,btkd->bkgt", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * hd ** -0.5
     mask = jnp.arange(pps * page)[None] < lens[:, None]
@@ -120,8 +128,8 @@ def test_paged_flash_decode_allclose(page, pps, dtype):
     B, kvH, G, hd = 3, 2, 4, 16
     npages = B * pps
     q = jax.random.normal(KEY, (B, kvH, G, hd), dtype)
-    kp = jax.random.normal(jax.random.PRNGKey(1), (npages, page, kvH, hd), dtype)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (npages, page, kvH, hd), dtype)
+    kp = jax.random.normal(jax.random.PRNGKey(1), (npages, kvH, page, hd), dtype)
+    vp = jax.random.normal(jax.random.PRNGKey(2), (npages, kvH, page, hd), dtype)
     rng = np.random.RandomState(0)
     perm = rng.permutation(npages)
     ptab = np.full((B, pps), npages, np.int32)  # sentinel = unmapped
@@ -129,7 +137,9 @@ def test_paged_flash_decode_allclose(page, pps, dtype):
     for b in range(B):
         used = -(-int(lens[b]) // page)
         ptab[b, :used] = perm[b * pps:b * pps + used]
-    got = ops.paged_flash_decode(q, kp, vp, jnp.asarray(ptab),
+    # the decode step's call: one query row per slot
+    got = ops.ragged_paged_flash(q, kp, vp, jnp.asarray(ptab),
+                                 jnp.arange(B, dtype=jnp.int32),
                                  jnp.asarray(lens))
     want = _paged_decode_ref(q, kp, vp, jnp.asarray(ptab), jnp.asarray(lens))
     # empty slot: kernel yields zeros, oracle yields a uniform average —
@@ -147,8 +157,8 @@ def test_ragged_paged_flash_allclose(page, pps):
     B, kvH, G, hd = 3, 2, 4, 16
     T = 11
     npages = B * pps
-    kp = jax.random.normal(jax.random.PRNGKey(1), (npages, page, kvH, hd))
-    vp = jax.random.normal(jax.random.PRNGKey(2), (npages, page, kvH, hd))
+    kp = jax.random.normal(jax.random.PRNGKey(1), (npages, kvH, page, hd))
+    vp = jax.random.normal(jax.random.PRNGKey(2), (npages, kvH, page, hd))
     q = jax.random.normal(KEY, (T, kvH, G, hd))
     rng = np.random.RandomState(3)
     perm = rng.permutation(npages)
@@ -171,10 +181,8 @@ def test_ragged_paged_flash_allclose(page, pps):
     got = ops.ragged_paged_flash(q, kp, vp, jnp.asarray(ptab),
                                  jnp.asarray(slot), jnp.asarray(lens))
     # oracle: gather each token's slot context, mask by its visible length
-    k = jnp.take(kp, jnp.asarray(ptab), axis=0,
-                 mode="clip").reshape(B, pps * page, kvH, hd)[slot]
-    v = jnp.take(vp, jnp.asarray(ptab), axis=0,
-                 mode="clip").reshape(B, pps * page, kvH, hd)[slot]
+    k = _gather_rows(kp, jnp.asarray(ptab))[slot]
+    v = _gather_rows(vp, jnp.asarray(ptab))[slot]
     s = jnp.einsum("tkgd,tskd->tkgs", q, k) * hd ** -0.5
     mask = jnp.arange(pps * page)[None] < jnp.asarray(lens)[:, None]
     s = jnp.where(mask[:, None, None, :], s, -1e30)
@@ -195,18 +203,21 @@ def test_flash_kernels_fused_dequant_match_fp32_pool(kernel):
     the bytes expand, not the math."""
     B, kvH, G, hd, page, pps = 2, 2, 4, 16, 8, 3
     npages = B * pps
-    kp_f = jax.random.normal(jax.random.PRNGKey(1), (npages, page, kvH, hd))
-    vp_f = jax.random.normal(jax.random.PRNGKey(2), (npages, page, kvH, hd))
-    kp8, ks = ops.quantize_kv(kp_f)
+    kp_f = jax.random.normal(jax.random.PRNGKey(1), (npages, kvH, page, hd))
+    vp_f = jax.random.normal(jax.random.PRNGKey(2), (npages, kvH, page, hd))
+    kp8, ks = ops.quantize_kv(kp_f)  # scales (npages, kvH, page)
     vp8, vs = ops.quantize_kv(vp_f)
     kp_dq = ops.dequantize_kv(kp8, ks)
     vp_dq = ops.dequantize_kv(vp8, vs)
+    ks, vs = ks[:, :, None, :], vs[:, :, None, :]  # scale-pool layout
     ptab = jnp.asarray(np.arange(npages).reshape(B, pps), jnp.int32)
     if kernel == "paged":
         q = jax.random.normal(KEY, (B, kvH, G, hd))
         lens = jnp.asarray([pps * page, page + 3], jnp.int32)
-        got = ops.paged_flash_decode(q, kp8, vp8, ptab, lens, ks=ks, vs=vs)
-        want = ops.paged_flash_decode(q, kp_dq, vp_dq, ptab, lens)
+        slot = jnp.arange(B, dtype=jnp.int32)
+        got = ops.ragged_paged_flash(q, kp8, vp8, ptab, slot, lens,
+                                     ks=ks, vs=vs)
+        want = ops.ragged_paged_flash(q, kp_dq, vp_dq, ptab, slot, lens)
     else:
         T = 5
         q = jax.random.normal(KEY, (T, kvH, G, hd))

@@ -80,12 +80,13 @@ def test_quantize_preserves_row_absmax_sign_and_extremes():
 
 def test_copy_pages_carries_scale_rows():
     """COW on an int8 pool must copy a page's scale row with its values —
-    `copy_pages` with the scale-pool axis duplicates rows exactly."""
+    `copy_pages` on a scale pool duplicates rows exactly."""
     rng = np.random.RandomState(2)
-    ks = jnp.asarray(rng.rand(6, 4, 2), jnp.float32)  # (n_pages, page, kvH)
+    # (n_pages, kvH, 1, page)
+    ks = jnp.asarray(rng.rand(6, 2, 1, 4), jnp.float32)
     src = jnp.asarray([1, 6], jnp.int32)  # second pair = sentinel no-op
     dst = jnp.asarray([3, 6], jnp.int32)
-    out = kops.copy_pages(ks, src, dst, axis=ks.ndim - 3)
+    out = kops.copy_pages(ks, src, dst)
     assert bool(jnp.all(out[3] == ks[1]))
     assert bool(jnp.all(out[:3] == ks[:3])) and bool(jnp.all(out[4:] == ks[4:]))
 
@@ -351,15 +352,13 @@ def test_select_serve_defaults_tunes_kv_dtype():
 
 
 def test_default_interpret_backend_and_env(monkeypatch):
-    """False iff the backend is a real TPU; REPRO_PALLAS_INTERPRET forces
-    either mode (the TPU-validation follow-up's prerequisite)."""
+    """False iff the backend is a real TPU, and no environment variable can
+    flip it: a chip run must never silently measure the interpreter."""
     from repro.kernels.ops import default_interpret
 
+    want = jax.default_backend() != "tpu"
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    assert default_interpret() == (jax.default_backend() != "tpu")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert default_interpret() is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "true")
-    assert default_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "garbage")  # ignored
-    assert default_interpret() == (jax.default_backend() != "tpu")
+    assert default_interpret() is want
+    for env in ("0", "1", "true", "false", "garbage"):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", env)
+        assert default_interpret() is want

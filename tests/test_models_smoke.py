@@ -90,6 +90,28 @@ def test_microbatched_step_matches_structure(arch):
     assert np.isfinite(float(metrics["loss"]))
 
 
+@pytest.mark.parametrize("arch", ARCH_NAMES)
+def test_init_stage_matches_per_repeat_loop(arch):
+    """``init_stage`` draws the repeats under vmap; each block must still be
+    bit-identical to drawing repeat r, block i from
+    ``fold_in(fold_in(key, r), i)`` one at a time."""
+    from repro.models.transformer import init_block, init_stage
+
+    cfg = get_config(arch, smoke=True)
+    for stage in cfg.stages:
+        got = init_stage(KEY, cfg, stage)
+        for r in range(stage.repeats):
+            kr = jax.random.fold_in(KEY, r)
+            for i, blk in enumerate(stage.pattern):
+                want = init_block(jax.random.fold_in(kr, i), cfg, blk)
+                have = (got[i] if stage.repeats == 1 else
+                        jax.tree.map(lambda x, r=r: x[r], got[i]))
+                assert jax.tree.structure(have) == jax.tree.structure(want)
+                for h, w in zip(jax.tree.leaves(have), jax.tree.leaves(want)):
+                    assert h.dtype == w.dtype
+                    np.testing.assert_array_equal(np.asarray(h), np.asarray(w))
+
+
 def test_param_counts_match_analytic():
     """Analytic param_count (roofline MODEL_FLOPS source) matches real trees
     on smoke configs."""

@@ -389,6 +389,20 @@ def test_page_pool_returns_to_initial_after_three_waves(qwen):
     assert len(eng._free) == eng.n_pages and eng.cached_pages == 0
 
 
+def test_reset_template_holds_no_pool_copy(qwen):
+    """The slot-reset template keeps every per-slot leaf of the decode
+    state but no copy of the shared KV pools (which the reset never reads
+    from it): on the chip a second pool would not fit beside the first."""
+    cfg, params = qwen
+    eng, uids, got = _serve(cfg, params, _prompts(cfg, [9, 17, 12, 5]))
+    assert sorted(got) == sorted(uids)  # 4 requests on 3 slots: a reuse
+    for st_blks, t_blks in zip(eng._state["layers"], eng._template["layers"]):
+        for s_blk, t_blk in zip(st_blks, t_blks):
+            pools = {"kp", "vp", "ks", "vs"} & set(s_blk)
+            assert pools and not pools & set(t_blk)
+            assert set(t_blk) == set(s_blk) - pools
+
+
 def test_submit_validation(qwen):
     cfg, params = qwen
     eng = ServeEngine(params, cfg, batch_size=2, cache_len=32, page_size=8)

@@ -86,3 +86,21 @@ def test_token_identity_flash_kernel_path(multidevice):
     t1 = _transcript(multidevice, 1, flash=True)
     t4 = _transcript(multidevice, 4, flash=True)
     assert t1 == t4, f"{t1}\nvs\n{t4}"
+
+
+def test_sharded_scenario_refuses_a_tpu_parent(monkeypatch):
+    """The forked-device scenario measures CPU children; from a parent on
+    a TPU backend it must refuse (that parent holds the chip) and point to
+    the in-process four-chip path instead of reporting CPU numbers."""
+    import importlib.util
+    from pathlib import Path
+
+    import jax
+
+    path = Path(__file__).resolve().parents[1] / "benchmarks" / "serve_sweep.py"
+    spec = importlib.util.spec_from_file_location("serve_sweep", path)
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="chip_smoke.py --four-chips"):
+        sweep.sharded_serve_scenario()

@@ -62,3 +62,26 @@ def test_hybrid_arch_trains():
     shape = ShapeCfg("hyb", 32, 4, "train")
     hist = TrainLoop(cfg, shape, lr=1e-3, total_steps=20).run(8)
     assert np.isfinite(hist[-1]["loss"])
+
+
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch, tmp_path):
+    """The launch entry points keep JAX's persistent compile cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says, setting nothing in code, and
+    otherwise at one fixed, git-ignored path inside the checkout."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    repo = Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        assert path == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
