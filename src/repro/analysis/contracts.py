@@ -138,9 +138,10 @@ def _build_programs(cfg, pshapes, sshapes, dims):
     every program ``ServeEngine.__init__`` jits — built from the SAME
     builders and the same ``STATE_DONATE_ARGNUM`` the engine uses."""
     from repro.models import model as M
-    from repro.serve.serve_step import (STATE_DONATE_ARGNUM,
+    from repro.serve.serve_step import (STATE_DONATE_ARGNUM, make_page_copy,
                                         make_page_gather, make_page_insert,
-                                        make_ragged_step, make_spec_rollback)
+                                        make_paged_step, make_ragged_step,
+                                        make_slot_reset, make_spec_rollback)
 
     B, chunk, T, pps, spec_k = (dims["B"], dims["chunk"], dims["T"],
                                 dims["pps"], dims["spec_k"])
@@ -148,8 +149,6 @@ def _build_programs(cfg, pshapes, sshapes, dims):
     page = _sds((), i32)
     page_data = jax.eval_shape(
         lambda s, p: M.gather_kv_page(cfg, s, p), sshapes, page)
-    step = lambda wl: (lambda p, s, t, qp, v: M.paged_step(
-        p, cfg, s, t, qp, v, with_logits=wl))
     donate = (STATE_DONATE_ARGNUM,)
     return [
         ("_ragged_step",
@@ -158,22 +157,19 @@ def _build_programs(cfg, pshapes, sshapes, dims):
           _sds((T,), i32), _sds((T,), i32), _sds((T,), b_),
           _sds((B, 1 + spec_k), i32)),
          donate, STATE_DONATE_ARGNUM, True),
-        ("_chunk_step", step(False),
+        ("_chunk_step", make_paged_step(cfg, with_logits=False),
          (pshapes, sshapes, _sds((B, chunk), i32), _sds((B, chunk), i32),
           _sds((B, chunk), b_)),
          donate, STATE_DONATE_ARGNUM, True),
-        ("_decode_step", step(True),
+        ("_decode_step", make_paged_step(cfg, with_logits=True),
          (pshapes, sshapes, _sds((B, 1), i32), _sds((B, 1), i32),
           _sds((B, 1), b_)),
          donate, STATE_DONATE_ARGNUM, True),
-        ("_reset",
-         lambda s, s0, m, rows, plen: M.reset_paged_slots(
-             cfg, s, s0, m, rows, plen),
+        ("_reset", make_slot_reset(cfg),
          (sshapes, sshapes, _sds((B,), b_), _sds((B, pps), i32),
           _sds((B,), i32)),
          (0,), 0, True),
-        ("_copy",
-         lambda s, src, dst: M.copy_kv_pages(cfg, s, src, dst),
+        ("_copy", make_page_copy(cfg),
          (sshapes, _sds((B,), i32), _sds((B,), i32)),
          (0,), 0, True),
         ("_gather_page", make_page_gather(cfg), (sshapes, page),

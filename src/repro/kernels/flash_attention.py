@@ -36,6 +36,11 @@ from repro.kernels._interpret import default_interpret
 
 NEG_INF = -1e30
 
+# the paged serving kernel's op name in the compiled program (the
+# ``tpu_custom_call`` instruction is named after it), by which a profiler
+# trace's ``XLA Ops`` line and a reduction of it find the kernel
+RAGGED_KERNEL = "_ragged_paged_flash"
+
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             bq: int, bk: int, nk: int, scale: float, window):
@@ -215,6 +220,7 @@ def _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs, *, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, kvH, G, hd), q.dtype),
         interpret=interpret,
+        name=RAGGED_KERNEL,
     )(*args)
 
 

@@ -155,7 +155,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
+import functools
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -171,8 +171,12 @@ from repro.serve.errors import (DeadlineExceeded, EngineOverloaded,
 from repro.serve.handle import Request, RequestHandle
 from repro.serve.pool import (PagePool, _PrefixNode, kv_bytes_per_token,
                               kv_page_bytes)
+from repro.serve import spans
 from repro.serve.scheduler import EngineView, Scheduler, make_scheduler
-from repro.serve.serve_step import STATE_DONATE_ARGNUM, make_ragged_step
+from repro.serve.serve_step import (STATE_DONATE_ARGNUM, make_page_copy,
+                                    make_page_gather, make_page_insert,
+                                    make_paged_step, make_ragged_step,
+                                    make_slot_reset, make_spec_rollback)
 
 from repro.core.roofline import KV_ITEMSIZE
 
@@ -426,16 +430,17 @@ class ServeEngine:
                                cfg, page_size, self.kv_dtype,
                                self._kv_shards)}
         # per-token / per-tick logs for the latency benchmark:
-        # token_log rows are (uid, tick index, wall time); tick_log rows are
-        # (had outstanding prefill at tick start, wall time at tick end)
+        # token_log rows are (uid, tick index, spans.now()); tick_log rows
+        # are (had outstanding prefill at tick start, spans.now() at tick end)
         self.token_log: List[tuple] = []
         self.tick_log: List[tuple] = []
 
         def _count_traces(fn):
-            def wrapper(*a):
+            @functools.wraps(fn)  # keeps the program's name
+            def counted(*a):
                 self._stats["traces"] += 1  # python body runs at trace time
                 return fn(*a)
-            return wrapper
+            return counted
 
         # donate the state (serve_step.STATE_DONATE_ARGNUM): the KV page
         # pools, int8 scale pools, and recurrent-state carries dominate the
@@ -451,26 +456,17 @@ class ServeEngine:
                 cfg, width=max(prefill_chunk + 1, 1 + self._spec_k),
                 flash_decode=flash_decode)),
             donate_argnums=donate)
-        step = lambda wl: (lambda p, s, t, qp, v: M.paged_step(
-            p, cfg, s, t, qp, v, with_logits=wl, flash_decode=flash_decode))
+        step = lambda wl: make_paged_step(cfg, with_logits=wl,
+                                          flash_decode=flash_decode)
         self._chunk_step = jax.jit(step(False), donate_argnums=donate)
         self._decode_step = jax.jit(step(True), donate_argnums=donate)
         # control-plane programs (admission reset, COW page copy) — separate
         # from the serve path, each traced at most once
-        self._reset = jax.jit(
-            lambda s, s0, m, rows, plen: M.reset_paged_slots(
-                cfg, s, s0, m, rows, plen),
-            donate_argnums=(0,))
-        self._copy = jax.jit(
-            lambda s, src, dst: M.copy_kv_pages(cfg, s, src, dst),
-            donate_argnums=(0,))
+        self._reset = jax.jit(make_slot_reset(cfg), donate_argnums=(0,))
+        self._copy = jax.jit(make_page_copy(cfg), donate_argnums=(0,))
         # tiered page movers: demotion gather (state stays live) and
         # promotion scatter (state donated, pools update in place); page id
         # is data, so each traces at most once for the engine's lifetime
-        from repro.serve.serve_step import (make_page_gather,
-                                            make_page_insert,
-                                            make_spec_rollback)
-
         self._gather_page = jax.jit(make_page_gather(cfg))
         self._insert_page = jax.jit(make_page_insert(cfg),
                                     donate_argnums=(0,))
@@ -526,7 +522,7 @@ class ServeEngine:
         self._uid += 1
         req = Request(self._uid, prompt, max_tokens, eos_id,
                       temperature=temperature, top_k=top_k, seed=seed,
-                      priority=priority)
+                      priority=priority, submitted_at=spans.now())
         if deadline_ticks is not None:
             req.deadline_tick = self._stats["ticks"] + deadline_ticks
         # admission reserves only the unmatched suffix on a prefix hit, but
@@ -634,17 +630,18 @@ class ServeEngine:
         The gather is materialized to numpy (host RAM IS the tier); the
         scatter donates the state, so pools update in place and jax's async
         dispatch overlaps the copy with the tick that follows."""
-        for ev in self.pool.drain_events():
-            if ev[0] == "demote":
-                _, page, slot = ev
-                rows = self._gather_page(state, np.int32(page))
-                self._host_store[slot] = jax.tree.map(np.asarray, rows)
-            elif ev[0] == "promote":
-                _, slot, page = ev
-                state = self._insert_page(state, self._host_store.pop(slot),
-                                          np.int32(page))
-            else:  # ("hevict", slot)
-                self._host_store.pop(ev[1], None)
+        with spans.span(spans.MOVERS):
+            for ev in self.pool.drain_events():
+                if ev[0] == "demote":
+                    _, page, slot = ev
+                    rows = self._gather_page(state, np.int32(page))
+                    self._host_store[slot] = jax.tree.map(np.asarray, rows)
+                elif ev[0] == "promote":
+                    _, slot, page = ev
+                    state = self._insert_page(
+                        state, self._host_store.pop(slot), np.int32(page))
+                else:  # ("hevict", slot)
+                    self._host_store.pop(ev[1], None)
         return state
 
     # -- admission --------------------------------------------------------
@@ -784,6 +781,8 @@ class ServeEngine:
             else:
                 ci += 1
                 admitted.add(req.uid)
+            if req.admitted_at is None:  # a resumed preemptee keeps its first
+                req.admitted_at = spans.now()
             mpages = self.pool.acquire(mpages)  # +1 ref each; promotes
             # host hits (events drained into device state in the epilogue)
             if n_host:
@@ -1120,11 +1119,12 @@ class ServeEngine:
         request (releasing its page refs) on EOS / max_tokens."""
         s = self.slots[b]
         req = s.req
+        t = spans.now()
+        if not req.out_tokens:
+            req.first_token_at = t
         req.out_tokens.append(tok)
         s.pos += 1
-        self.token_log.append((req.uid, self._stats["ticks"],
-                               # servelint: ignore[hot-nondeterminism] — measurement-only: the wall time lands in token_log for the latency benchmark and never feeds control flow
-                               time.perf_counter()))
+        self.token_log.append((req.uid, self._stats["ticks"], t))
         if (len(req.out_tokens) >= req.max_tokens
                 or (req.eos_id is not None and tok == req.eos_id)):
             req.done = True
@@ -1156,172 +1156,179 @@ class ServeEngine:
         feature on.  After the step the engine accepts the longest
         agreeing draft prefix (plus the correction/bonus token) and rolls
         back kpos/slen for rejected tails."""
-        T, W = self.budget, max(self.chunk + 1, 1 + self._spec_k)
-        R = 1 + self._spec_k
-        tokens = np.zeros(T, np.int32)
-        slot = np.zeros(T, np.int32)
-        q_pos = np.zeros(T, np.int32)
-        seq_idx = np.full(T, W, np.int32)
-        valid = np.zeros(T, bool)
-        logit_idx = np.full((self.B, R) if self._spec_k else (self.B,),
-                            T, np.int32)
-        n = 0
-        sampling: List[int] = []
-        tick = self._stats["ticks"]
-        ready = [b for b, s in enumerate(self.slots)
-                 if s is not None and s.ready_tick <= tick
-                 and s.fill >= len(s.prefill_tokens)]
-        filling = [b for b, s in enumerate(self.slots)
-                   if s is not None and s.ready_tick <= tick
-                   and s.fill < len(s.prefill_tokens)]
-        if self._default_pack:
-            decode_order, prefill_order = ready, filling
-        else:
-            view = self._view(include_queue=False)
-            decode_order = self._pack_order(
-                self.scheduler.decode_order(view, ready), ready,
-                "decode_order")
-            prefill_order = self._pack_order(
-                self.scheduler.prefill_order(view, filling), filling,
-                "prefill_order")
-        for b in decode_order:
-            s = self.slots[b]
-            tokens[n] = s.last_tok
-            slot[n] = b
-            q_pos[n] = s.pos
-            seq_idx[n] = 0
-            valid[n] = True
-            if self._spec_k:
-                logit_idx[b, 0] = n
+        with spans.span(spans.PACK):
+            T, W = self.budget, max(self.chunk + 1, 1 + self._spec_k)
+            R = 1 + self._spec_k
+            tokens = np.zeros(T, np.int32)
+            slot = np.zeros(T, np.int32)
+            q_pos = np.zeros(T, np.int32)
+            seq_idx = np.full(T, W, np.int32)
+            valid = np.zeros(T, bool)
+            logit_idx = np.full((self.B, R) if self._spec_k else (self.B,),
+                                T, np.int32)
+            n = 0
+            sampling: List[int] = []
+            tick = self._stats["ticks"]
+            ready = [b for b, s in enumerate(self.slots)
+                     if s is not None and s.ready_tick <= tick
+                     and s.fill >= len(s.prefill_tokens)]
+            filling = [b for b, s in enumerate(self.slots)
+                       if s is not None and s.ready_tick <= tick
+                       and s.fill < len(s.prefill_tokens)]
+            if self._default_pack:
+                decode_order, prefill_order = ready, filling
             else:
-                logit_idx[b] = n
-            sampling.append(b)
-            n += 1
-        for b in prefill_order:
-            if n >= T:
-                break
-            s = self.slots[b]
-            L = len(s.prefill_tokens)
-            c = min(self.chunk, L - s.fill, T - n)
-            tokens[n:n + c] = s.prefill_tokens[s.fill:s.fill + c]
-            slot[n:n + c] = b
-            q_pos[n:n + c] = s.fill + np.arange(c)
-            seq_idx[n:n + c] = np.arange(c)
-            valid[n:n + c] = True
-            n += c
-            s.fill += c
-            self._index_filled_pages(s)
-            if s.fill >= L:
-                # decode resumes from the last prompt token at position L
-                # (same scheme as the reference engine, for token identity)
-                # — or, for a re-prefilled preemptee, from its last
-                # generated token at its preempted position (L here is the
-                # replayed-history length, which IS that position)
-                s.pos = L
-                s.last_tok = (s.resume_tok if s.resume_tok is not None
-                              else int(s.prefill_tokens[-1]))
-                if n < T:
-                    tokens[n] = s.last_tok
-                    slot[n] = b
-                    q_pos[n] = s.pos
-                    seq_idx[n] = c
-                    valid[n] = True
-                    if self._spec_k:
-                        logit_idx[b, 0] = n
-                    else:
-                        logit_idx[b] = n
-                    sampling.append(b)
-                    n += 1
-        # draft section: leftover budget takes each decoding slot's prompt-
-        # lookup chain at its next consecutive positions.  The drafted dict
-        # is the tick's DRAFT LEDGER: slot -> proposed tokens, with the
-        # verify rows at logit_idx[b, 1:1+k].  Drafts never displace decode
-        # or prefill tokens and a lookup miss packs nothing, so this
-        # section is free for non-repetitive traffic.
-        drafted: Dict[int, List[int]] = {}
-        if self._spec_k:
+                view = self._view(include_queue=False)
+                decode_order = self._pack_order(
+                    self.scheduler.decode_order(view, ready), ready,
+                    "decode_order")
+                prefill_order = self._pack_order(
+                    self.scheduler.prefill_order(view, filling), filling,
+                    "prefill_order")
             for b in decode_order:
+                s = self.slots[b]
+                tokens[n] = s.last_tok
+                slot[n] = b
+                q_pos[n] = s.pos
+                seq_idx[n] = 0
+                valid[n] = True
+                if self._spec_k:
+                    logit_idx[b, 0] = n
+                else:
+                    logit_idx[b] = n
+                sampling.append(b)
+                n += 1
+            for b in prefill_order:
                 if n >= T:
                     break
                 s = self.slots[b]
-                req = s.req
-                # cap by remaining output (drafting past max_tokens-1 can
-                # never be accepted) and by leftover budget
-                room = min(self._spec_k,
-                           req.max_tokens - len(req.out_tokens) - 1, T - n)
-                if room < 1:
-                    continue
-                hist = (np.concatenate([req.prompt, np.asarray(
-                            req.out_tokens, np.int32)])
-                        if req.out_tokens else req.prompt)
-                d = self._draft(hist, room)
-                if not d:
-                    continue
-                k = len(d)
-                if __debug__:
-                    # the rejected-tail contract (see PagePool.is_indexed):
-                    # draft rows land beyond the prompt, in pages the slot
-                    # privately owns — never in indexed prefix pages
-                    for pi in range((s.pos + 1) // self.page_size,
-                                    (s.pos + k) // self.page_size + 1):
-                        assert not self.pool.is_indexed(s.pages[pi]), \
-                            (b, pi, s.pages[pi])
-                tokens[n:n + k] = d
-                slot[n:n + k] = b
-                q_pos[n:n + k] = s.pos + 1 + np.arange(k)
-                seq_idx[n:n + k] = 1 + np.arange(k)
-                valid[n:n + k] = True
-                logit_idx[b, 1:1 + k] = n + np.arange(k)
-                drafted[b] = d
-                self._stats["spec_drafted"] += k
-                n += k
+                L = len(s.prefill_tokens)
+                c = min(self.chunk, L - s.fill, T - n)
+                tokens[n:n + c] = s.prefill_tokens[s.fill:s.fill + c]
+                slot[n:n + c] = b
+                q_pos[n:n + c] = s.fill + np.arange(c)
+                seq_idx[n:n + c] = np.arange(c)
+                valid[n:n + c] = True
+                n += c
+                s.fill += c
+                self._index_filled_pages(s)
+                if s.fill >= L:
+                    # decode resumes from the last prompt token at position L
+                    # (same scheme as the reference engine, for token identity)
+                    # — or, for a re-prefilled preemptee, from its last
+                    # generated token at its preempted position (L here is the
+                    # replayed-history length, which IS that position)
+                    s.pos = L
+                    s.last_tok = (s.resume_tok if s.resume_tok is not None
+                                  else int(s.prefill_tokens[-1]))
+                    if n < T:
+                        tokens[n] = s.last_tok
+                        slot[n] = b
+                        q_pos[n] = s.pos
+                        seq_idx[n] = c
+                        valid[n] = True
+                        if self._spec_k:
+                            logit_idx[b, 0] = n
+                        else:
+                            logit_idx[b] = n
+                        sampling.append(b)
+                        n += 1
+            # draft section: leftover budget takes each decoding slot's prompt-
+            # lookup chain at its next consecutive positions.  The drafted dict
+            # is the tick's DRAFT LEDGER: slot -> proposed tokens, with the
+            # verify rows at logit_idx[b, 1:1+k].  Drafts never displace decode
+            # or prefill tokens and a lookup miss packs nothing, so this
+            # section is free for non-repetitive traffic.
+            drafted: Dict[int, List[int]] = {}
+            if self._spec_k:
+                for b in decode_order:
+                    if n >= T:
+                        break
+                    s = self.slots[b]
+                    req = s.req
+                    # cap by remaining output (drafting past max_tokens-1 can
+                    # never be accepted) and by leftover budget
+                    room = min(self._spec_k,
+                               req.max_tokens - len(req.out_tokens) - 1, T - n)
+                    if room < 1:
+                        continue
+                    hist = (np.concatenate([req.prompt, np.asarray(
+                                req.out_tokens, np.int32)])
+                            if req.out_tokens else req.prompt)
+                    d = self._draft(hist, room)
+                    if not d:
+                        continue
+                    k = len(d)
+                    if __debug__:
+                        # the rejected-tail contract (see PagePool.is_indexed):
+                        # draft rows land beyond the prompt, in pages the slot
+                        # privately owns — never in indexed prefix pages
+                        for pi in range((s.pos + 1) // self.page_size,
+                                        (s.pos + k) // self.page_size + 1):
+                            assert not self.pool.is_indexed(s.pages[pi]), \
+                                (b, pi, s.pages[pi])
+                    tokens[n:n + k] = d
+                    slot[n:n + k] = b
+                    q_pos[n:n + k] = s.pos + 1 + np.arange(k)
+                    seq_idx[n:n + k] = 1 + np.arange(k)
+                    valid[n:n + k] = True
+                    logit_idx[b, 1:1 + k] = n + np.arange(k)
+                    drafted[b] = d
+                    self._stats["spec_drafted"] += k
+                    n += k
         results: Dict[int, List[int]] = {}
         if n == 0:
             return state, results
-        logits, state = self._ragged_step(self.params, state, tokens, slot,
-                                          q_pos, seq_idx, valid, logit_idx)
+        with spans.span(spans.DISPATCH):
+            logits, state = self._ragged_step(self.params, state, tokens, slot,
+                                              q_pos, seq_idx, valid, logit_idx)
         self._stats["ragged_ticks"] += 1
         self._stats["packed_tokens"] += n
         if sampling:
-            rows = np.asarray(logits)  # (B, V) — or (B, R, V) with spec on
-            self._stats["sampled_slot_ticks"] += len(sampling)
-            accepted: Dict[int, int] = {}
-            for b in sampling:
-                req = self.slots[b].req
-                drafts = drafted.get(b, ())
-                # verify in one pass: row j holds the model's prediction
-                # given the draft prefix d_1..d_j, so sampling row j both
-                # CHECKS draft j+1 and, on mismatch or exhaustion, IS the
-                # correction/bonus token — the chain always emits >= 1
-                j = 0
-                tok = self._sample(req, rows[b, 0] if self._spec_k
-                                   else rows[b], len(req.out_tokens))
-                while True:
-                    self._finish_token(b, tok, results)
-                    if (self.slots[b] is None or j >= len(drafts)
-                            or tok != drafts[j]):
-                        break
-                    j += 1
-                    self._stats["spec_accepted"] += 1
-                    tok = self._sample(req, rows[b, j], len(req.out_tokens))
-                accepted[b] = j
-            if drafted:
-                # roll back rejected tails: drop kpos/slen for positions at
-                # and beyond the slot's new write position.  Released slots
-                # skip it — admission's reset wipes the whole row anyway.
-                mask = np.zeros(self.B, bool)
-                new_len = np.zeros(self.B, np.int32)
-                for b, d in drafted.items():
-                    j = accepted.get(b, 0)
-                    if j < len(d):
-                        self._stats["spec_rejected"] += len(d) - j
-                        s = self.slots[b]
-                        if s is not None:
-                            mask[b] = True
-                            new_len[b] = s.pos
-                if mask.any():
-                    state = self._spec_rollback(state, mask, new_len)
-                    self._stats["spec_rollbacks"] += int(mask.sum())
+            with spans.span(spans.WAIT):
+                jax.block_until_ready(logits)
+            with spans.span(spans.FETCH):
+                rows = np.asarray(logits)  # (B, V), or (B, R, V) with spec
+            with spans.span(spans.SAMPLE):
+                self._stats["sampled_slot_ticks"] += len(sampling)
+                accepted: Dict[int, int] = {}
+                for b in sampling:
+                    req = self.slots[b].req
+                    drafts = drafted.get(b, ())
+                    # verify in one pass: row j holds the model's prediction
+                    # given the draft prefix d_1..d_j, so sampling row j both
+                    # CHECKS draft j+1 and, on mismatch or exhaustion, IS the
+                    # correction/bonus token — the chain always emits >= 1
+                    j = 0
+                    tok = self._sample(req, rows[b, 0] if self._spec_k
+                                       else rows[b], len(req.out_tokens))
+                    while True:
+                        self._finish_token(b, tok, results)
+                        if (self.slots[b] is None or j >= len(drafts)
+                                or tok != drafts[j]):
+                            break
+                        j += 1
+                        self._stats["spec_accepted"] += 1
+                        tok = self._sample(req, rows[b, j],
+                                           len(req.out_tokens))
+                    accepted[b] = j
+                if drafted:
+                    # roll back rejected tails: drop kpos/slen for positions at
+                    # and beyond the slot's new write position.  Released slots
+                    # skip it — admission's reset wipes the whole row anyway.
+                    mask = np.zeros(self.B, bool)
+                    new_len = np.zeros(self.B, np.int32)
+                    for b, d in drafted.items():
+                        j = accepted.get(b, 0)
+                        if j < len(d):
+                            self._stats["spec_rejected"] += len(d) - j
+                            s = self.slots[b]
+                            if s is not None:
+                                mask[b] = True
+                                new_len[b] = s.pos
+                    if mask.any():
+                        state = self._spec_rollback(state, mask, new_len)
+                        self._stats["spec_rollbacks"] += int(mask.sum())
         return state, results
 
     # -- legacy two-phase path (PR 1, kept behind ragged=False) -----------
@@ -1438,21 +1445,22 @@ class ServeEngine:
         Public so continuous-arrival drivers (benchmarks/serve_sweep.py) and
         ``RequestHandle.tokens()`` iterators can interleave ``submit`` with
         serving instead of draining a batch."""
-        with self._ctx():
+        with spans.step(spans.TICK, step_num=self._stats["ticks"]), \
+                self._ctx():
             self._ensure_state()
             self._expire_deadlines()
             self._chaos_alloc_fail = False
             if self.fault_injector is not None and self._chaos_tick():
                 # stalled tick: the clock advanced, nothing ran
                 self._stats["ticks"] += 1
-                # servelint: ignore[hot-nondeterminism] — measurement-only: tick_log wall time, never control flow
-                self.tick_log.append((False, time.perf_counter()))
+                self.tick_log.append((False, spans.now()))
                 return {}
             if self.pool.events:
                 # expiry / cancellation may have dropped parked pages with
                 # no admission round behind them to drain the hevicts
                 self._state = self._apply_pool_events(self._state)
-            self._state = self._admit(self._state)
+            with spans.span(spans.ADMIT):
+                self._state = self._admit(self._state)
             had_prefill = any(s is not None
                               and s.fill < len(s.prefill_tokens)
                               for s in self.slots)
@@ -1463,10 +1471,9 @@ class ServeEngine:
                 self._state = self._prefill_tick(self._state)
             elif any(s is not None for s in self.slots):
                 self._state, results = self._decode_tick(self._state)
-        self._stats["ticks"] += 1
-        # servelint: ignore[hot-nondeterminism] — measurement-only: tick_log wall time, never control flow
-        self.tick_log.append((had_prefill, time.perf_counter()))
-        return results
+            self._stats["ticks"] += 1
+            self.tick_log.append((had_prefill, spans.now()))
+            return results
 
     def run(self, max_ticks: int = 4096) -> Dict[int, List[int]]:
         """Drain the queue; returns {uid: generated tokens}."""

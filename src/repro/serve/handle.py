@@ -67,6 +67,12 @@ class Request:
     # raised by RequestHandle.result()/tokens().  None for normal
     # completion and for client-initiated cancels.
     error: Optional[Exception] = None
+    # host-clock stamps (``serve.spans.now()``): submission, the first
+    # admission to a slot (a preempted request re-admitted keeps it) and
+    # the first output token; None until each happens
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
 
 
 class RequestHandle(int):

@@ -1,9 +1,11 @@
 """The jit-compiled serving steps + state sharding rules.
 
-Two step builders live here:
+Three step builders live here:
 
 - ``make_serve_step`` — the legacy lock-step decode step (one token per
   slot, shared positions; kept for the reference engine path).
+- ``make_paged_step`` — the two-phase path's chunk and decode steps
+  (``ServeEngine(ragged=False)``).
 - ``make_ragged_step`` — the serving engine's ONE compiled program: a flat
   (T,) token pack in which every entry carries its own (slot, position,
   validity), so any mix of prefill-chunk tokens and decode tokens runs
@@ -81,6 +83,24 @@ from repro.parallel.sharding import logical_spec
 # with ``donate_argnums=(STATE_DONATE_ARGNUM,)`` so the pools update in place
 STATE_DONATE_ARGNUM = 1
 
+# The name of each engine program.  A jitted program's module is named
+# ``jit_<name>``, which is how a profiler trace's ``XLA Modules`` line and a
+# reduction of it tell the programs apart; of these names only the serve
+# step's holds "wrapper".
+SERVE_STEP = "serve_step_wrapper"
+CHUNK_STEP = "paged_chunk_step"
+DECODE_STEP = "paged_decode_step"
+SLOT_RESET = "slot_reset"
+PAGE_COPY = "cow_page_copy"
+PAGE_GATHER = "page_gather"
+PAGE_INSERT = "page_insert"
+SPEC_ROLLBACK = "spec_rollback"
+
+
+def _named(fn, name: str):
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
 
 def make_serve_step(cfg: ModelCfg, *, sp_decode: bool = False):
     def serve_step(params, state, tokens_t):
@@ -109,7 +129,42 @@ def make_ragged_step(cfg: ModelCfg, *, width: int, flash_decode: bool = False):
                              seq_idx, valid, logit_idx, width=width,
                              flash_decode=flash_decode)
 
-    return ragged_step
+    return _named(ragged_step, SERVE_STEP)
+
+
+def make_paged_step(cfg: ModelCfg, *, with_logits: bool,
+                    flash_decode: bool = False):
+    """The two-phase path's steps (``ServeEngine(ragged=False)``):
+    ``f(params, state, tokens, q_pos, valid) -> (logits, state)`` over
+    (B, C) chunks, the chunk step without logits and the decode step with
+    them (see ``models.model.paged_step``).  Jit with state donation."""
+    def paged_step(params, state, tokens, q_pos, valid):
+        return M.paged_step(params, cfg, state, tokens, q_pos, valid,
+                            with_logits=with_logits,
+                            flash_decode=flash_decode)
+
+    return _named(paged_step, DECODE_STEP if with_logits else CHUNK_STEP)
+
+
+def make_slot_reset(cfg: ModelCfg):
+    """Admission's reset: ``f(state, template, mask, rows, plen) -> state``
+    giving each masked slot its block-table row and the positions it
+    inherits (see ``models.model.reset_paged_slots``).  Jit with
+    ``donate_argnums=(0,)``."""
+    def slot_reset(state, template, mask, rows, plen):
+        return M.reset_paged_slots(cfg, state, template, mask, rows, plen)
+
+    return _named(slot_reset, SLOT_RESET)
+
+
+def make_page_copy(cfg: ModelCfg):
+    """Copy-on-write: ``f(state, src, dst) -> state`` copying pool pages
+    ``src`` to ``dst`` (see ``models.model.copy_kv_pages``).  Jit with
+    ``donate_argnums=(0,)``."""
+    def page_copy(state, src, dst):
+        return M.copy_kv_pages(cfg, state, src, dst)
+
+    return _named(page_copy, PAGE_COPY)
 
 
 def make_page_gather(cfg: ModelCfg):
@@ -120,7 +175,7 @@ def make_page_gather(cfg: ModelCfg):
     def page_gather(state, page):
         return M.gather_kv_page(cfg, state, page)
 
-    return page_gather
+    return _named(page_gather, PAGE_GATHER)
 
 
 def make_page_insert(cfg: ModelCfg):
@@ -132,7 +187,7 @@ def make_page_insert(cfg: ModelCfg):
     def page_insert(state, page_data, page):
         return M.insert_kv_page(cfg, state, page_data, page)
 
-    return page_insert
+    return _named(page_insert, PAGE_INSERT)
 
 
 def make_spec_rollback(cfg: ModelCfg):
@@ -146,7 +201,7 @@ def make_spec_rollback(cfg: ModelCfg):
     def spec_rollback(state, mask, new_len):
         return M.rollback_paged_slots(cfg, state, mask, new_len)
 
-    return spec_rollback
+    return _named(spec_rollback, SPEC_ROLLBACK)
 
 
 # leaf name -> logical axes for decode-state leaves (unstacked; a scanned

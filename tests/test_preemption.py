@@ -188,6 +188,26 @@ def test_preempt_resume_reprefill_token_identical(qwen):
     assert _leak_free(eng)
 
 
+@pytest.mark.parametrize("host_pages", [6, 0], ids=["park-hit", "reprefill"])
+def test_preempted_request_keeps_its_first_admission_stamp(qwen, host_pages):
+    cfg, params = qwen
+    hog, chat = _prompts(cfg, [16, 6])
+    eng = _engine(params, cfg, max_pages=4, host_pages=host_pages,
+                  scheduler="slo")
+    h_hog = eng.submit(hog, max_tokens=16)
+    for _ in range(4):
+        eng.tick()
+    first = h_hog.request.admitted_at
+    h_chat = eng.submit(chat, max_tokens=3, priority=1)
+    eng.run()
+    assert eng.stats["preemptions"] == 1 and eng.stats["resumes"] == 1
+    assert h_hog.request.admitted_at == first
+    for h in (h_hog, h_chat):
+        r = h.request
+        assert r.submitted_at <= r.admitted_at <= r.first_token_at
+    assert h_hog.request.first_token_at < h_chat.request.admitted_at
+
+
 def test_preempt_off_stalls_instead(qwen):
     cfg, params = qwen
     eng, got_hog, got_chat = _overload_run(params, cfg, host_pages=6,

@@ -12,7 +12,10 @@ module is imported: only one process at a time may load the TPU library,
 and every test worker imports this file.
 """
 import functools
+import json
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,10 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import flash_attention as fa
 
 HD, PAGE, T, B, CACHE_LEN = 128, 16, 128, 8, 2048
+# the regex by which the benchmark's trace reduction finds the kernel's ops
+KERNEL_RE = re.compile(json.loads(
+    (Path(__file__).resolve().parents[1] / "bench" / "cells"
+     / "qwen2-1.5b.chat.json").read_text())["trace"]["kernel"])
 PPS = CACHE_LEN // PAGE
 N_PAGES = B * PPS
 
@@ -58,7 +65,13 @@ def _pool_shapes(sharding, kvH, kv_dtype):
 
 
 def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+    """The kernel is in the compiled program, under an instruction name
+    the benchmark's ``trace.kernel`` finds."""
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    names = [ln.split("=", 1)[0].strip().lstrip("%") for ln in calls]
+    assert all(KERNEL_RE.search(n) for n in names), names
 
 
 @pytest.mark.parametrize("kvH,G,kv_dtype", [
