@@ -45,12 +45,13 @@ def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None):
     -> (T,kvH,G,hd).
 
     Prefix-shared pages need no kernel support: the kernel resolves
-    token -> slot -> page through ``ptab`` per grid step, so two slots whose
-    block-table rows point at the same pool page simply DMA the same tile —
-    sharing and copy-on-write are entirely a host-side allocator concern.
-    int8 pools pass scale pools ``ks``/``vs`` ((n_pages,kvH,1,page) f32);
-    dequant is fused into the kernel's inner loop, so the HBM traffic per
-    page is the int8 bytes plus one scale row — not a dequantized copy."""
+    run -> slot -> page through ``ptab`` for every page it fetches, so two
+    slots whose block-table rows point at the same pool page simply DMA the
+    same bytes — sharing and copy-on-write are entirely a host-side
+    allocator concern.  int8 pools pass scale pools ``ks``/``vs``
+    ((n_pages,kvH,1,page) f32); dequant is fused into the kernel's inner
+    loop, so the HBM traffic per page is the int8 bytes plus its scales —
+    not a dequantized copy."""
     return _fa.ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=ks, vs=vs,
                                   interpret=default_interpret())
 

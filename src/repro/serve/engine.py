@@ -165,6 +165,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelCfg
+from repro.kernels.flash_attention import ragged_visits
 from repro.models import model as M
 from repro.serve.errors import (DeadlineExceeded, EngineOverloaded,
                                 RequestTooLarge)
@@ -382,6 +383,9 @@ class ServeEngine:
         self._state = None  # persistent: the pool doubles as the prefix cache
         self._stats = {"chunk_ticks": 0, "decode_ticks": 0, "ragged_ticks": 0,
                        "ticks": 0, "packed_tokens": 0, "traces": 0,
+                       # the ragged kernel's work: runs and KV tiles one
+                       # layer's call visits, summed over ragged ticks
+                       "attn_runs": 0, "attn_kv_tiles": 0,
                        "pages_in_use_peak": 0, "admissions": 0,
                        "prefix_hits": 0, "prefix_tokens_reused": 0,
                        "cow_copies": 0, "cancelled": 0,
@@ -456,6 +460,24 @@ class ServeEngine:
                 cfg, width=max(prefill_chunk + 1, 1 + self._spec_k),
                 flash_decode=flash_decode)),
             donate_argnums=donate)
+        # under ``mesh=`` the decode state is built by one program, in place
+        # on its shards: a pool larger than one device's free memory never
+        # sits whole on one device
+        self._sharded_state = (jax.jit(self._fresh_state)
+                               if mesh is not None else None)
+        # counted on the host from each pack, with the kernel's own run cut
+        # and tile sizes at the first paged layer's per-device shapes
+        self._kernel_visits = None
+        if flash_decode and self._has_paged:
+            a = next(blk.attn for st in cfg.stages for blk in st.pattern
+                     if blk.mixer == "attn" and blk.attn.window is None)
+            kvH = a.num_kv_heads
+            if kvH % self._kv_shards == 0:  # else the pools stay replicated
+                kvH //= self._kv_shards
+            self._kernel_visits = functools.partial(
+                ragged_visits, kvH=kvH, G=a.num_heads // a.num_kv_heads,
+                hd=a.head_dim, page=page_size, pps=self.pps,
+                kv_dtype=self.kv_dtype)
         step = lambda wl: make_paged_step(cfg, with_logits=wl,
                                           flash_decode=flash_decode)
         self._chunk_step = jax.jit(step(False), donate_argnums=donate)
@@ -1284,6 +1306,11 @@ class ServeEngine:
                                               q_pos, seq_idx, valid, logit_idx)
         self._stats["ragged_ticks"] += 1
         self._stats["packed_tokens"] += n
+        if self._kernel_visits is not None:
+            runs, tiles = self._kernel_visits(
+                slot, np.where(valid, q_pos + 1, 0))
+            self._stats["attn_runs"] += runs
+            self._stats["attn_kv_tiles"] += tiles
         if sampling:
             with spans.span(spans.WAIT):
                 jax.block_until_ready(logits)
@@ -1414,21 +1441,11 @@ class ServeEngine:
         from its committed operands — no per-call in_shardings needed, and
         donation keeps the sharded pools updating in place."""
         if self._state is None:
-            self._state = M.init_paged_state(
-                self.params, self.cfg, self.B, self.cache_len,
-                page_size=self.page_size, n_pages=self.n_pages,
-                window_extra=self.chunk, kv_dtype=self.kv_dtype)
-            if self.mesh is not None:
-                from repro.serve.serve_step import decode_state_specs
-
+            if self.mesh is None:
+                self._state = self._fresh_state(self.params)
+            else:
                 with self._ctx():
-                    specs = decode_state_specs(jax.tree.map(
-                        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                        self._state))
-                ns = jax.tree.map(
-                    lambda s: NamedSharding(self.mesh, s), specs,
-                    is_leaf=lambda x: isinstance(x, P))
-                self._state = jax.device_put(self._state, ns)
+                    self._state = self._sharded_state(self.params)
                 self.params = jax.device_put(
                     self.params, NamedSharding(self.mesh, P()))
             # the reset template must not alias the (donated) live state;
@@ -1438,6 +1455,24 @@ class ServeEngine:
                 [{n: jax.numpy.copy(x) for n, x in blk.items()
                   if n not in ("kp", "vp", "ks", "vs")} for blk in stage]
                 for stage in self._state["layers"]]}
+
+    def _fresh_state(self, params):
+        """The engine's empty decode state (pools, block tables, lengths).
+        Under ``mesh=`` it is traced inside ``_sharded_state``, which gives
+        every leaf its NamedSharding."""
+        state = M.init_paged_state(
+            params, self.cfg, self.B, self.cache_len,
+            page_size=self.page_size, n_pages=self.n_pages,
+            window_extra=self.chunk, kv_dtype=self.kv_dtype)
+        if self.mesh is None:
+            return state
+        from repro.serve.serve_step import decode_state_specs
+
+        specs = decode_state_specs(jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state))
+        return jax.lax.with_sharding_constraint(state, jax.tree.map(
+            lambda s: NamedSharding(self.mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, P)))
 
     def tick(self) -> Dict[int, List[int]]:
         """One scheduling tick: admit from the queue, pack, run one program

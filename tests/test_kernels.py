@@ -6,7 +6,9 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
+from repro.models.layers.attention import _gather_paged_kv, _paged_masked_attn
 
 KEY = jax.random.PRNGKey(0)
 
@@ -149,13 +151,92 @@ def test_paged_flash_decode_allclose(page, pps, dtype):
     assert not bool(jnp.isnan(got).any())
 
 
-@pytest.mark.parametrize("page,pps", [(8, 4), (16, 2)])
-def test_ragged_paged_flash_allclose(page, pps):
-    """Ragged query packs: per-token slot -> block-table -> pool-row double
+def _edge_pack(page, pps, kvH, G, T, seed=3):
+    """A pack at the kernel's edges, with its block table: decode
+    singletons whose lengths sit at, and one beside, page and KV-tile
+    edges, one of length 1 (a length-1 run); a prefill run longer than the
+    q tile; an invalid separator, then a second run of slot 1 (one slot in
+    two non-adjacent runs, as a draft chain sits apart from its decode
+    token); an invalid tail.  Block-table columns past each slot's length
+    hold the unmapped sentinel."""
+    tq, ppt = fa.ragged_tile_sizes(kvH, G, 16, page, pps, jnp.float32)
+    tk, cap = ppt * page, pps * page
+    edges = [1, page - 1, page, page + 1, tk - 1, tk, tk + 1, cap]
+    fills = [min(x, cap) for x in edges]
+    run = min(tq + tq // 2 + 3, T - len(fills) - 6)
+    assert run > tq, (run, tq)
+    fills.append(cap)  # the prefill slot
+    B = len(fills)
+    npages = B * pps
+    rng = np.random.RandomState(seed)
+    perm = rng.permutation(npages)
+    ptab = np.full((B, pps), npages, np.int32)
+    for b in range(B):
+        used = -(-fills[b] // page)
+        ptab[b, :used] = perm[b * pps:b * pps + used]
+    slot, lens = [], []
+    for b in range(B - 1):
+        slot.append(b)
+        lens.append(fills[b])
+    for j in range(run):
+        slot.append(B - 1)
+        lens.append(cap - run + 1 + j)
+    slot += [0, 1, 1]
+    lens += [0, fills[1] - 1, fills[1]]
+    slot += [0] * (T - len(slot))
+    lens += [0] * (T - len(lens))
+    return (np.asarray(ptab), np.asarray(slot, np.int32),
+            np.asarray(lens, np.int32), npages)
+
+
+def _jnp_path(q, kp, vp, ptab, slot, lens, ks=None, vs=None):
+    """The engine's jnp attention path on a pack: the block-table gather
+    (dequantizing int8 pools) and the per-token masked softmax, every key
+    at its own position and each token at ``lens - 1``."""
+    cache = {"kp": kp, "vp": vp, "ptab": jnp.asarray(ptab)}
+    if ks is not None:
+        cache.update(ks=ks, vs=vs)
+    k, v = _gather_paged_kv(cache, jnp.float32)
+    kpos = jnp.broadcast_to(jnp.arange(k.shape[1]), (len(slot), k.shape[1]))
+    o = _paged_masked_attn(q.astype(jnp.float32)[:, None], k[slot], v[slot],
+                           kpos, jnp.asarray(lens)[:, None] - 1, None)
+    return o[:, 0]
+
+
+@pytest.mark.parametrize("pack,page,pps,kvH,G,dtype", [
+    pytest.param("mixed", 8, 4, 2, 4, jnp.float32, id="8-4"),
+    pytest.param("mixed", 16, 2, 2, 4, jnp.float32, id="16-2"),
+    pytest.param("edges", 16, 12, 2, 6, jnp.bfloat16, id="edges-gqa-bf16"),
+    pytest.param("edges", 8, 20, 2, 6, jnp.float32, id="edges-gqa-page8"),
+    pytest.param("edges", 16, 12, 5, 1, jnp.bfloat16, id="edges-tp-bf16"),
+])
+def test_ragged_paged_flash_allclose(pack, page, pps, kvH, G, dtype):
+    """Ragged query packs: per-run slot -> block-table -> pool-row double
     indirection, per-token visible-length masking (intra-pack causality),
-    and invalid (lens == 0) tokens yielding zeros."""
-    B, kvH, G, hd = 3, 2, 4, 16
-    T = 11
+    and invalid (lens == 0) tokens yielding zeros.  The two small packs
+    check against a gather oracle; the edge packs against the engine's
+    jnp path, over several KV tiles and q tiles."""
+    hd = 16
+    if pack == "edges":
+        T = 96 if G == 1 else 40
+        ptab, slot, lens, npages = _edge_pack(page, pps, kvH, G, T)
+        kp = jax.random.normal(jax.random.PRNGKey(1),
+                               (npages, kvH, page, hd)).astype(dtype)
+        vp = jax.random.normal(jax.random.PRNGKey(2),
+                               (npages, kvH, page, hd)).astype(dtype)
+        q = jax.random.normal(KEY, (T, kvH, G, hd)).astype(dtype)
+        got = ops.ragged_paged_flash(q, kp, vp, jnp.asarray(ptab),
+                                     jnp.asarray(slot), jnp.asarray(lens))
+        want = _jnp_path(q, kp, vp, ptab, slot, lens)
+        valid = lens > 0
+        np.testing.assert_allclose(np.asarray(got[valid], np.float32),
+                                   np.asarray(want[valid], np.float32),
+                                   **_tol(dtype))
+        np.testing.assert_allclose(np.asarray(got[~valid], np.float32), 0.0,
+                                   atol=1e-6)
+        assert not bool(jnp.isnan(got).any())
+        return
+    B, T = 3, 11
     npages = B * pps
     kp = jax.random.normal(jax.random.PRNGKey(1), (npages, kvH, page, hd))
     vp = jax.random.normal(jax.random.PRNGKey(2), (npages, kvH, page, hd))
@@ -195,14 +276,26 @@ def test_ragged_paged_flash_allclose(page, pps):
     assert not bool(jnp.isnan(got).any())
 
 
-@pytest.mark.parametrize("kernel", ["paged", "ragged"])
+@pytest.mark.parametrize("kernel", ["paged", "ragged", "edges-gqa",
+                                    "edges-tp"])
 def test_flash_kernels_fused_dequant_match_fp32_pool(kernel):
     """int8 pools: the fused in-VMEM dequant (int8 tile × scale row inside
     the online-softmax loop) must match running the same kernel on the
     dequantized fp32 pool to float tolerance — quantization changes WHERE
-    the bytes expand, not the math."""
-    B, kvH, G, hd, page, pps = 2, 2, 4, 16, 8, 3
-    npages = B * pps
+    the bytes expand, not the math.  The edge packs also match the jnp
+    path on the int8 pool."""
+    hd = 16
+    if kernel.startswith("edges"):
+        kvH, G = (2, 6) if kernel == "edges-gqa" else (5, 1)
+        page, pps = 16, 12
+        T = 96 if G == 1 else 40
+        ptab, slot, lens, npages = _edge_pack(page, pps, kvH, G, T, seed=5)
+        ptab, slot, lens = (jnp.asarray(x) for x in (ptab, slot, lens))
+        q = jax.random.normal(KEY, (T, kvH, G, hd))
+    else:
+        B, kvH, G, page, pps = 2, 2, 4, 8, 3
+        npages = B * pps
+        ptab = jnp.asarray(np.arange(npages).reshape(B, pps), jnp.int32)
     kp_f = jax.random.normal(jax.random.PRNGKey(1), (npages, kvH, page, hd))
     vp_f = jax.random.normal(jax.random.PRNGKey(2), (npages, kvH, page, hd))
     kp8, ks = ops.quantize_kv(kp_f)  # scales (npages, kvH, page)
@@ -210,24 +303,81 @@ def test_flash_kernels_fused_dequant_match_fp32_pool(kernel):
     kp_dq = ops.dequantize_kv(kp8, ks)
     vp_dq = ops.dequantize_kv(vp8, vs)
     ks, vs = ks[:, :, None, :], vs[:, :, None, :]  # scale-pool layout
-    ptab = jnp.asarray(np.arange(npages).reshape(B, pps), jnp.int32)
     if kernel == "paged":
         q = jax.random.normal(KEY, (B, kvH, G, hd))
         lens = jnp.asarray([pps * page, page + 3], jnp.int32)
         slot = jnp.arange(B, dtype=jnp.int32)
-        got = ops.ragged_paged_flash(q, kp8, vp8, ptab, slot, lens,
-                                     ks=ks, vs=vs)
-        want = ops.ragged_paged_flash(q, kp_dq, vp_dq, ptab, slot, lens)
-    else:
+    elif kernel == "ragged":
         T = 5
         q = jax.random.normal(KEY, (T, kvH, G, hd))
         slot = jnp.asarray([0, 1, 0, 1, 0], jnp.int32)
         lens = jnp.asarray([1, page, pps * page, 0, page + 2], jnp.int32)
-        got = ops.ragged_paged_flash(q, kp8, vp8, ptab, slot, lens,
-                                     ks=ks, vs=vs)
-        want = ops.ragged_paged_flash(q, kp_dq, vp_dq, ptab, slot, lens)
+    got = ops.ragged_paged_flash(q, kp8, vp8, ptab, slot, lens, ks=ks, vs=vs)
+    want = ops.ragged_paged_flash(q, kp_dq, vp_dq, ptab, slot, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+    if kernel.startswith("edges"):
+        path = _jnp_path(q, kp8, vp8, ptab, slot, lens, ks, vs)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(path),
+                                   rtol=2e-4, atol=2e-4)
+
+
+# the chat cell's per-chip shapes (8-token q tiles, 128-position KV tiles)
+# and the tp4 cell's (64-token q tiles)
+CHAT = dict(kvH=2, G=6, hd=128, page=16, pps=128, kv_dtype=jnp.bfloat16)
+TP4 = dict(CHAT, kvH=5, G=1)
+
+
+def _pack(pieces, T=128):
+    """(slot, lens) of a pack from (slot, first lens, tokens) pieces, one
+    token per position; slot -1 is an invalid stretch; invalid tail."""
+    slot, lens = [], []
+    for b, first, n in pieces:
+        slot += [max(b, 0)] * n
+        lens += [0] * n if b < 0 else list(range(first, first + n))
+    return (np.asarray(slot + [0] * (T - len(slot)), np.int32),
+            np.asarray(lens + [0] * (T - len(lens)), np.int32))
+
+
+@pytest.mark.parametrize("shapes,pieces,runs,tiles", [
+    # decode singletons at a length-1, a tile edge and one past it
+    (CHAT, [(0, 1, 1), (1, 128, 1), (2, 129, 1)], 3, 1 + 1 + 2),
+    # a prefill chunk and its decode token: 21 tokens of one slot, cut at
+    # the 8-token q tiles into 8 + 8 + 5, lengths across the tile edge
+    (CHAT, [(3, 120, 21)], 3, 1 + 2 + 2),
+    # speculation: slot 0's decode token, then a prefill, then its draft
+    # chain apart from it, so slot 0 is two runs
+    (CHAT, [(0, 50, 1), (1, 300, 1), (2, 10, 3), (0, 51, 2)], 4,
+     1 + 3 + 1 + 1),
+    # an invalid token between two stretches of one slot splits them
+    (CHAT, [(4, 10, 2), (-1, 0, 1), (4, 12, 1)], 2, 2),
+    # tp4: 64-token q tiles, so a 70-token run is two
+    (TP4, [(0, 1000, 70)], 2, 9 + 9),
+], ids=["decode-singletons", "prefill-run", "spec-two-runs", "invalid-gap",
+        "tp4-q-tile"])
+def test_ragged_visits_hand_built_packs(shapes, pieces, runs, tiles):
+    slot, lens = _pack(pieces)
+    assert fa.ragged_visits(slot, lens, **shapes) == (runs, tiles)
+
+
+@pytest.mark.parametrize("shapes", [CHAT, TP4], ids=["chat", "tp4"])
+def test_ragged_visits_match_the_kernels_run_table(shapes):
+    """The host counter and the kernel's on-device run table agree: same
+    run cut, same tile size, on random packs of decode tokens, chunks,
+    repeats of one slot and invalid tokens."""
+    rng = np.random.RandomState(0)
+    tq, ppt = fa.ragged_tile_sizes(shapes["kvH"], shapes["G"], shapes["hd"],
+                                   shapes["page"], shapes["pps"],
+                                   shapes["kv_dtype"])
+    for _ in range(20):
+        pieces = [(int(rng.randint(-1, 6)), int(rng.randint(1, 1900)),
+                   int(rng.randint(1, 40))) for _ in range(8)]
+        slot, lens = _pack(pieces, T=400)
+        slot, lens = slot[:128], lens[:128]
+        _, table, n_items = fa._run_table(jnp.asarray(slot), jnp.asarray(lens),
+                                          tq, ppt * shapes["page"])
+        want = (int((np.asarray(table[2]) > 0).sum()), int(n_items[0]))
+        assert fa.ragged_visits(slot, lens, **shapes) == want
 
 
 # ---------------------------------------------------------------------------

@@ -703,3 +703,42 @@ def test_admission_feasible_when_match_pins_all_evictable_pages(qwen):
     assert eng.stats["prefix_tokens_reused"] == 16  # full-page match kept
     assert (eng._ref == 0).all()
     assert eng.reclaimable_pages == eng.n_pages
+
+
+@pytest.mark.parametrize("spec_k", [0, 3], ids=["plain", "speculative"])
+def test_kernel_visit_counters_match_the_helper(qwen, spec_k):
+    """``attn_runs`` / ``attn_kv_tiles`` are the kernel helper's runs and
+    KV tiles summed over the packs the engine dispatched; with
+    speculation a slot's draft chain sits apart from its decode token, so
+    some pack holds one slot in two runs."""
+    from repro.kernels.flash_attention import ragged_visits
+
+    cfg, params = qwen
+    eng = ServeEngine(params, cfg, batch_size=3, cache_len=CACHE, page_size=8,
+                      prefill_chunk=16, token_budget=48, flash_decode=True,
+                      spec_k=spec_k)
+    packs = []
+    step = eng._ragged_step
+
+    def recording(params, state, tokens, slot, q_pos, seq_idx, valid, li):
+        packs.append((slot.copy(), np.where(valid, q_pos + 1, 0)))
+        return step(params, state, tokens, slot, q_pos, seq_idx, valid, li)
+
+    eng._ragged_step = recording
+    rng = np.random.RandomState(5)
+    prompts = ([np.tile(rng.randint(0, cfg.vocab_size, 4), 6)
+                for _ in range(3)] if spec_k else _prompts(cfg, [5, 30, 17]))
+    for p in prompts:
+        eng.submit(p, max_tokens=6)
+    eng.run()
+    a = next(blk.attn for st in cfg.stages for blk in st.pattern
+             if blk.mixer == "attn")
+    shapes = dict(kvH=a.num_kv_heads, G=a.num_heads // a.num_kv_heads,
+                  hd=a.head_dim, page=8, pps=eng.pps, kv_dtype=eng.kv_dtype)
+    visits = [ragged_visits(s, n, **shapes) for s, n in packs]
+    assert eng.stats["attn_runs"] == sum(r for r, _ in visits) > 0
+    assert eng.stats["attn_kv_tiles"] == sum(t for _, t in visits) > 0
+    if spec_k:
+        assert eng.stats["spec_drafted"] > 0
+        assert any(r > len(set(s[n > 0].tolist()))
+                   for (s, n), (r, _) in zip(packs, visits))

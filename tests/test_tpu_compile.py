@@ -4,7 +4,8 @@ Interpret-mode tests check the kernel's numbers; only the chip compiler
 checks its tiling (Mosaic refuses blocks whose last two dims are neither
 (8, 128)-aligned nor whole).  These compile the paged flash kernel at
 published widths for one v5e chip — qwen2-1.5b (GQA kvH 2, G 6) and one
-device's share of qwen1.5-4b under a 4-way KV-head split (kvH 5, G 1) —
+device's share of qwen1.5-4b under a 4-way KV-head split (kvH 5, G 1),
+at a small batch and at each benchmark cell's per-chip batch and pool —
 and assert the Pallas kernel is in the compiled program.
 
 The topology is described inside a module-scoped fixture, never while a
@@ -31,6 +32,9 @@ KERNEL_RE = re.compile(json.loads(
      / "qwen2-1.5b.chat.json").read_text())["trace"]["kernel"])
 PPS = CACHE_LEN // PAGE
 N_PAGES = B * PPS
+# each cell's per-chip shapes: (batch, pool pages)
+CHAT = (64, 8192)  # qwen2-1.5b.chat
+TP4 = (16, 2048)  # qwen1.5-4b.tp4-decode, one chip's 5 of 20 KV heads
 
 
 @pytest.fixture(scope="module")
@@ -56,11 +60,11 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _pool_shapes(sharding, kvH, kv_dtype):
+def _pool_shapes(sharding, kvH, kv_dtype, n_pages=N_PAGES):
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
-    pools = [sds((N_PAGES, kvH, PAGE, HD), kv_dtype)] * 2
+    pools = [sds((n_pages, kvH, PAGE, HD), kv_dtype)] * 2
     if kv_dtype == jnp.int8:
-        pools += [sds((N_PAGES, kvH, 1, PAGE), jnp.float32)] * 2
+        pools += [sds((n_pages, kvH, 1, PAGE), jnp.float32)] * 2
     return sds, pools
 
 
@@ -74,18 +78,24 @@ def _assert_kernel(compiled):
     assert all(KERNEL_RE.search(n) for n in names), names
 
 
-@pytest.mark.parametrize("kvH,G,kv_dtype", [
-    (2, 6, jnp.bfloat16),  # qwen2-1.5b
-    (2, 6, jnp.int8),
-    (5, 1, jnp.bfloat16),  # qwen1.5-4b, one device of a 4-way split
-    (5, 1, jnp.int8),
+@pytest.mark.parametrize("kvH,G,kv_dtype,batch,n_pages", [
+    (2, 6, jnp.bfloat16, B, N_PAGES),  # qwen2-1.5b
+    (2, 6, jnp.int8, B, N_PAGES),
+    (5, 1, jnp.bfloat16, B, N_PAGES),  # qwen1.5-4b, one device of a 4-way split
+    (5, 1, jnp.int8, B, N_PAGES),
+    (2, 6, jnp.bfloat16) + CHAT,
+    (5, 1, jnp.bfloat16) + TP4,
 ], ids=["qwen2-1.5b-bf16", "qwen2-1.5b-int8", "qwen1.5-4b-tp4-bf16",
-        "qwen1.5-4b-tp4-int8"])
-def test_ragged_paged_flash_compiles_for_v5e(one_chip, kvH, G, kv_dtype):
-    sds, pools = _pool_shapes(one_chip, kvH, kv_dtype)
+        "qwen1.5-4b-tp4-int8", "qwen2-1.5b.chat-cell", "qwen1.5-4b.tp4-cell"])
+def test_ragged_paged_flash_compiles_for_v5e(one_chip, kvH, G, kv_dtype, batch,
+                                             n_pages):
+    """At each cell's exact per-chip shapes too (T 128, 128 pages per
+    slot): the kernel's tiles fit VMEM, and every kernel instruction keeps
+    the name the cells' ``trace.kernel`` finds."""
+    sds, pools = _pool_shapes(one_chip, kvH, kv_dtype, n_pages)
     kp, vp, *scales = pools
     q = sds((T, kvH, G, HD), jnp.bfloat16)
-    ptab = sds((B, PPS), jnp.int32)
+    ptab = sds((batch, PPS), jnp.int32)
     vec = sds((T,), jnp.int32)
 
     def step(q, kp, vp, ptab, slot, lens, *scales):
