@@ -48,24 +48,42 @@ def init_attention(key, d: int, cfg: AttnCfg):
     return p
 
 
-def _project_q(params, cfg: AttnCfg, x):
+def _project_q(params, cfg: AttnCfg, x, acc=None):
+    """q in ``acc`` (the activation dtype by default), the dtype of the
+    bias add."""
     dt = x.dtype
-    q = jnp.einsum("bsd,dkgh->bskgh", x, params["wq"].astype(dt))
+    q = jnp.einsum("bsd,dkgh->bskgh", x, params["wq"].astype(dt)).astype(acc or dt)
     if cfg.qkv_bias:
-        q = q + params["bq"].astype(dt)
+        q = q + params["bq"].astype(dt).astype(q.dtype)
     return lshard(q, "act_batch", "act_seq", "act_kv_heads", "act_heads", None)
 
 
-def _project_kv(params, cfg: AttnCfg, x):
+def _project_kv(params, cfg: AttnCfg, x, acc=None):
     dt = x.dtype
-    k = jnp.einsum("bsd,dkh->bskh", x, params["wk"].astype(dt))
-    v = jnp.einsum("bsd,dkh->bskh", x, params["wv"].astype(dt))
+    k = jnp.einsum("bsd,dkh->bskh", x, params["wk"].astype(dt)).astype(acc or dt)
+    v = jnp.einsum("bsd,dkh->bskh", x, params["wv"].astype(dt)).astype(acc or dt)
     if cfg.qkv_bias:
-        k = k + params["bk"].astype(dt)
-        v = v + params["bv"].astype(dt)
+        k = k + params["bk"].astype(dt).astype(k.dtype)
+        v = v + params["bv"].astype(dt).astype(v.dtype)
     k = lshard(k, "act_batch", "act_seq", "act_kv_heads", None)
     v = lshard(v, "act_batch", "act_seq", "act_kv_heads", None)
     return k, v
+
+
+def _serving_qkv(params, cfg: AttnCfg, x, q_pos):
+    """q, k, v of a serving step, in the activation dtype.  The bias add and
+    the rotary embedding run in float32 and round once, after the rotation.
+    In the activation dtype the compiler may keep the sum in float32 into
+    the rotation or round it first, fusion by fusion, and a KV-head mesh's
+    program and one device's program choose differently: the engine's
+    outputs on four devices then part from one device's."""
+    dt = x.dtype
+    q = _project_q(params, cfg, x, jnp.float32)
+    k, v = _project_kv(params, cfg, x, jnp.float32)
+    if cfg.rope_theta is not None:
+        q = apply_rope(q, q_pos, cfg.rope_theta)
+        k = apply_rope(k, q_pos, cfg.rope_theta)
+    return q.astype(dt), k.astype(dt), v.astype(dt)
 
 
 def _out_proj(params, cfg: AttnCfg, o):
@@ -81,9 +99,11 @@ def _out_proj_replicated(params, cfg: AttnCfg, o):
     # head-contracting einsum.  Under the engine's KV-head TP mesh this is
     # the single op that contracts across the sharded axis; left to GSPMD it
     # becomes a partial dot + psum, whose summation order depends on the
-    # device count — replicating first (an exact all-gather) keeps engine
-    # outputs bit-identical across 1/2/4 devices, which the invariance
-    # suite asserts.  No-op without a mesh.
+    # device count — replicating first (an exact all-gather) gives every
+    # device one device's contraction.  The invariance suite finds engine
+    # outputs bit-identical across 1/2/4 CPU devices at its small sizes;
+    # that is not a promise at other sizes or on a TPU (see PERF.md).
+    # No-op without a mesh.
     o = lshard(o, *([None] * o.ndim))
     return _out_proj(params, cfg, o)
 
@@ -356,11 +376,8 @@ def paged_attention_step(params, cfg: AttnCfg, x, cache, q_pos, valid, *,
     tokens (False rows/tails: no cache write, output ignored by the engine).
     """
     B, C, _ = x.shape
-    q = _project_q(params, cfg, x)  # (B,C,kvH,G,hd)
-    k_new, v_new = _project_kv(params, cfg, x)  # (B,C,kvH,hd)
-    if cfg.rope_theta is not None:
-        q = apply_rope(q, q_pos, cfg.rope_theta)
-        k_new = apply_rope(k_new, q_pos, cfg.rope_theta)
+    # (B,C,kvH,G,hd), (B,C,kvH,hd) x2
+    q, k_new, v_new = _serving_qkv(params, cfg, x, q_pos)
 
     b_iota = jnp.broadcast_to(jnp.arange(B)[:, None], (B, C))
     paged = "kp" in cache
@@ -424,11 +441,8 @@ def ragged_attention_step(params, cfg: AttnCfg, x, cache, slot, q_pos, valid,
     scatter nowhere and their outputs are garbage the engine never reads.
     """
     T = x.shape[1]
-    q = _project_q(params, cfg, x)[0]  # (T,kvH,G,hd)
-    k_new, v_new = (t[0] for t in _project_kv(params, cfg, x))  # (T,kvH,hd)
-    if cfg.rope_theta is not None:
-        q = apply_rope(q[None], q_pos[None], cfg.rope_theta)[0]
-        k_new = apply_rope(k_new[None], q_pos[None], cfg.rope_theta)[0]
+    # (T,kvH,G,hd), (T,kvH,hd) x2
+    q, k_new, v_new = (t[0] for t in _serving_qkv(params, cfg, x, q_pos[None]))
 
     paged = "kp" in cache
     cache = dict(cache)

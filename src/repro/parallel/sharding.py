@@ -159,7 +159,11 @@ def make_serve_rules(mesh: Mesh) -> Dict[str, Any]:
     pack vectors) is device-count-agnostic and only attention's per-head
     work shrinks per device.  Attention outputs are constrained back to
     replicated before the output projection, making every collective an
-    exact all-gather (token-identical to the 1-device engine)."""
+    exact all-gather: no sum depends on the device count.  The invariance
+    suite finds the engine bit-identical to the 1-device engine at its
+    small CPU sizes; elsewhere a per-head slice of a projection may
+    accumulate in another order, and on a TPU at full depth the outputs
+    part in the last bits (see PERF.md)."""
     ax = "model" if "model" in mesh.axis_names else mesh.axis_names[-1]
     rules = make_rules(mesh, decode=True)
     rules.update({

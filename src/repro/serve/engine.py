@@ -140,9 +140,15 @@ heads ``[d·kvH/N, (d+1)·kvH/N)`` of every page); block tables, positions,
 and fill counts replicate.  The contract is strict layering: the HOST
 bookkeeping (PagePool / Scheduler / slot state / byte budget) is global and
 never sees the device count, while the DEVICE programs run under the serve
-mesh rules and keep outputs bit-identical across device counts (the
-attention output is replicated before the one head-contracting einsum, so
-no device-count-dependent partial-sum order exists).  ``stats`` reports
+mesh rules with no device-count-dependent partial-sum order (the attention
+output is replicated before the one head-contracting einsum) and the bias
+add and rotation rounded once (``attention._serving_qkv``).  The
+invariance suite (``tests/test_serve_tp.py``: the qwen1.5-4b smoke model,
+d 64, 4 heads) finds outputs bit-identical on 1, 2 and 4 CPU devices.
+That is not a promise at other sizes: a projection split by head is a
+smaller matrix product, which XLA may accumulate in another order, and
+on a TPU at full depth the outputs part in the last bits (PERF.md).
+``stats`` reports
 ``kv_shards`` / ``n_devices`` / ``kv_pool_bytes_per_device``.
 
 **Statically gated invariants**: the contracts above — one serve-path
