@@ -46,13 +46,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.findings import Finding
+from repro.models.layers.attention import POOL_LEAVES
 
 __all__ = ["POOL_LEAVES", "pool_buffer_pointers", "assert_donated",
            "check_contracts", "SERVE_PROGRAMS"]
-
-# the pool-sized decode-state leaves the no-copy contract covers: KV value
-# pools and their int8 scale pools (serve_step.STATE_AXES names the rest)
-POOL_LEAVES = ("kp", "vp", "ks", "vs")
 
 _RULE = "donation-contract"
 

@@ -118,7 +118,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 # Pool layout is HEAD-MAJOR: kp/vp (n_pages, kvH, page, hd), int8 scale
 # pools ks/vs (n_pages, kvH, 1, page).  All KV heads of one page are one
 # contiguous slab, so a page is one DMA per pool, and the KV-head loop runs
-# inside the kernel.
+# inside the kernel.  A scanned stage stacks its layers' pools, (L, n_pages,
+# ...); the kernel takes the stack whole with the layer as a scalar and
+# DMAs from that layer's plane.
 
 # a q tile aims at this many query rows (tq tokens × G heads)
 _Q_ROWS = 64
@@ -191,9 +193,9 @@ def _run_table(slot, lens, tq: int, tk: int):
     return rid, runs, jnp.sum(-(-runs[2] // tk), keepdims=True)
 
 
-def _ragged_kernel(runs_ref, n_items_ref, ptab_ref, q_ref, rlen_ref, rrun_ref,
-                   *rest, rq: int, ppt: int, page: int, scale: float,
-                   quantized: bool):
+def _ragged_kernel(runs_ref, n_items_ref, ptab_ref, layer_ref, q_ref, rlen_ref,
+                   rrun_ref, *rest, rq: int, ppt: int, page: int,
+                   scale: float, quantized: bool):
     if quantized:
         (kp, vp, ks, vs, o_ref, kbuf, vbuf, ksbuf, vsbuf, sems,
          m_ref, l_ref, acc_ref) = rest
@@ -202,22 +204,24 @@ def _ragged_kernel(runs_ref, n_items_ref, ptab_ref, q_ref, rlen_ref, rrun_ref,
         kp, vp, o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref = rest
         scales = ()
     kvH, _, hd = q_ref.shape
-    npages, pps = kp.shape[0], ptab_ref.shape[1]
+    npages, pps = kp.shape[1], ptab_ref.shape[1]
     tk = ppt * page
     n_items = n_items_ref[0]
+    layer = layer_ref[0]
 
     def copies(r, j, b):
         """Tile j of run r as DMAs into buffer b: one per page per value
-        pool and, for int8, one (kvH, 1, tk) row of the slot's scales per
-        scale pool.  Columns past the block table clamp to its last entry
-        and unmapped sentinel pages to a real pool row: their entries lie
-        beyond every row's length and are masked."""
+        pool, from the layer's plane of the stacked pool, and, for int8,
+        one (kvH, 1, tk) row of the slot's scales per scale pool.  Columns
+        past the block table clamp to its last entry and unmapped sentinel
+        pages to a real pool row: their entries lie beyond every row's
+        length and are masked."""
         s = runs_ref[0, r]
         out = []
         for i in range(ppt):
             pid = jnp.minimum(ptab_ref[s, jnp.minimum(j * ppt + i, pps - 1)],
                               npages - 1)
-            out += [pltpu.make_async_copy(src.at[pid], buf.at[b, :, i],
+            out += [pltpu.make_async_copy(src.at[layer, pid], buf.at[b, :, i],
                                           sems.at[n, b])
                     for n, (src, buf) in enumerate(((kp, kbuf), (vp, vbuf)))]
         out += [pltpu.make_async_copy(src.at[s, :, :, pl.ds(j * tk, tk)],
@@ -286,7 +290,7 @@ def _ragged_kernel(runs_ref, n_items_ref, ptab_ref, q_ref, rlen_ref, rrun_ref,
 
 
 def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None, *,
-                       interpret=None):
+                       layer=None, interpret=None):
     """Ragged-pack attention over a paged KV pool (one serving tick).
 
     q: (T, kvH, G, hd) — T pack tokens from arbitrary slots; slot: (T,)
@@ -295,6 +299,12 @@ def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None, *,
     kp, vp: (n_pages, kvH, page, hd); ptab: (B, pps) int32 block table
     (entries >= n_pages = unmapped).  Returns (T, kvH, G, hd).  Full
     (non-windowed) causal layers only.
+
+    ``layer``: a scalar index into pools stacked over a scanned stage's
+    layers, (L, n_pages, kvH, page, hd) (scales (L, n_pages, kvH, 1,
+    page)).  The kernel DMAs its pages from that layer's plane of the
+    stacked buffer, so the serving step never slices one layer's pool out.
+    None: the pools are one layer's, read as layer 0 of a 1-layer stack.
 
     Refcounted prefix-shared pages (serve.engine) require NO kernel change:
     every K/V tile is fetched through the run -> slot -> page indirection
@@ -311,14 +321,20 @@ def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None, *,
     """
     if interpret is None:  # resolve outside the jit boundary (static key)
         interpret = default_interpret()
+    if layer is None:
+        kp, vp, ks, vs = (None if x is None else x[None]
+                          for x in (kp, vp, ks, vs))
+        layer = 0
     return _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs,
+                               jnp.reshape(layer, (1,)).astype(jnp.int32),
                                interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs, *, interpret):
+def _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs, layer, *,
+                        interpret):
     T, kvH, G, hd = q.shape
-    npages, page = kp.shape[0], kp.shape[2]
+    npages, page = kp.shape[1], kp.shape[3]
     B, pps = ptab.shape
     quantized = ks is not None
     tq, ppt = ragged_tile_sizes(kvH, G, hd, page, pps, kp.dtype)
@@ -347,8 +363,8 @@ def _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs, *, interpret):
         cols = -(-pps // ppt) * ppt
         at = jnp.pad(ptab, ((0, 0), (0, cols - pps)), constant_values=npages)
 
-        def slot_rows(x):
-            x = jnp.take(x, at, axis=0, mode="clip")  # (B, cols, kvH, 1, page)
+        def slot_rows(x):  # (B, cols, kvH, 1, page) of the layer's plane
+            x = x.at[layer[0], at].get(mode="clip")
             return jnp.transpose(x, (0, 2, 3, 1, 4)).reshape(
                 B, kvH, 1, cols * page)
 
@@ -372,7 +388,7 @@ def _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs, *, interpret):
         functools.partial(_ragged_kernel, rq=rq, ppt=ppt, page=page,
                           scale=hd ** -0.5, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(1,),
             in_specs=[whole((kvH, rows, hd)), whole((rows, 1)),
                       whole((rows, 1))]
@@ -386,7 +402,7 @@ def _ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks, vs, *, interpret):
             vmem_limit_bytes=max(32 << 20, vmem + (8 << 20))),
         interpret=interpret,
         name=RAGGED_KERNEL,
-    )(runs, n_items, ptab, qr, rlen, rrun, *pools)
+    )(runs, n_items, ptab, layer, qr, rlen, rrun, *pools)
     return jnp.swapaxes(out.reshape(kvH, Tp, G, hd), 0, 1)[:T]
 
 

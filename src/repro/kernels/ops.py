@@ -39,10 +39,12 @@ def flash_attention(q, k, v, *, bq=128, bk=128, window=None):
                                interpret=default_interpret())
 
 
-def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None):
+def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None, *,
+                       layer=None):
     """Ragged-pack serving attention over a block-table-paged KV pool.
-    q: (T,kvH,G,hd); slot/lens: (T,); kp/vp: (n_pages,kvH,page,hd)
-    -> (T,kvH,G,hd).
+    q: (T,kvH,G,hd); slot/lens: (T,); kp/vp: (n_pages,kvH,page,hd), or
+    (L,n_pages,kvH,page,hd) stacked over a scanned stage with ``layer``
+    the scalar index of the plane to read -> (T,kvH,G,hd).
 
     Prefix-shared pages need no kernel support: the kernel resolves
     run -> slot -> page through ``ptab`` for every page it fetches, so two
@@ -53,7 +55,7 @@ def ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None):
     loop, so the HBM traffic per page is the int8 bytes plus its scales —
     not a dequantized copy."""
     return _fa.ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=ks, vs=vs,
-                                  interpret=default_interpret())
+                                  layer=layer, interpret=default_interpret())
 
 
 # ---------------------------------------------------------------------------

@@ -262,6 +262,11 @@ def prefill_cache(params, cfg: AttnCfg, cache, x, positions):
 # pools are exact replays of the cold path.
 
 
+# the paged cache's pool leaves, shared by every slot: KV value pools and
+# their int8 scale pools (the rest of a paged cache is per slot)
+POOL_LEAVES = ("kp", "vp", "ks", "vs")
+
+
 def kv_cache_dtype(kv_dtype, act_dtype):
     """Resolve a ``kv_dtype`` spec (None | str | dtype) to a jnp dtype.
     None means "follow the activation dtype" (the unquantized default)."""
@@ -327,38 +332,50 @@ def _paged_masked_attn(q, k, v, kpos, q_pos, window):
     return jnp.einsum("bkgqt,btkd->bqkgd", p, v)
 
 
-def _scatter_paged_kv(cache, k_new, v_new, page, off):
+def _scatter_paged_kv(cache, k_new, v_new, page, off, layer=None):
     """Scatter new K/V rows into the head-major pools at ``[page, :, off]``
     — the single write path shared by the two-phase and ragged steps.
-    k_new/v_new: (..., kvH, hd) with ``page``/``off`` of shape (...).  int8
-    pools quantize on write: values into ``kp``/``vp`` and their scales into
-    ``ks``/``vs`` at ``[page, :, 0, off]``; OOB sentinel pages drop the
-    write either way.  Mutates the caller's cache dict (callers own a fresh
-    copy)."""
+    k_new/v_new: (..., kvH, hd) with ``page``/``off`` of shape (...).
+    ``layer`` indexes pools stacked over a scanned stage's layers, (L,
+    n_pages, ...); None for one layer's pools.  int8 pools quantize on
+    write: values into ``kp``/``vp`` and their scales into ``ks``/``vs`` at
+    ``[page, :, 0, off]``; OOB sentinel pages drop the write either way.
+    Each pool takes one scatter over (token, KV head) pairs, each an
+    ``hd``-long row along the pool's minor dim: the TPU compiler writes
+    that in the pool's own layout, in place, where a scatter whose window
+    spans the heads would have it copy the whole pool into another layout
+    and back; under a KV-head mesh each device writes its own heads.  Mutates
+    the caller's cache dict (callers own a fresh copy)."""
     from repro.kernels import ops as kops
 
+    kvH = k_new.shape[-2]
+    head = jnp.arange(kvH)
+    at = (() if layer is None else (layer,)) + (
+        page[..., None], head, off[..., None])
     for name, rows in (("k", k_new), ("v", v_new)):
-        pool = cache[name + "p"]
         if name + "s" in cache:  # int8 pool: fused quantize-on-write
             rows, s = kops.quantize_kv(rows)
-            cache[name + "s"] = cache[name + "s"].at[page, :, 0, off].set(
-                s, mode="drop")
-        cache[name + "p"] = pool.at[page, :, off].set(
-            rows.astype(pool.dtype), mode="drop")
+            cache[name + "s"] = cache[name + "s"].at[
+                at[:-1] + (0, at[-1])].set(s, mode="drop")
+        pool = cache[name + "p"]
+        cache[name + "p"] = pool.at[at].set(rows.astype(pool.dtype),
+                                            mode="drop")
 
 
-def _gather_paged_kv(cache, dtype):
+def _gather_paged_kv(cache, dtype, layer=None):
     """Gather the whole block-table context from the pool, dequantizing int8
     pools against their per-entry scales (the jnp oracle of the fused kernel
-    path).  Returns (k, v) of shape (B, pps * page, kvH, hd) in ``dtype``.
+    path).  ``layer`` as in ``_scatter_paged_kv``.  Returns (k, v) of shape
+    (B, pps * page, kvH, hd) in ``dtype``.
     """
     from repro.kernels import ops as kops
 
+    at = (cache["ptab"],) if layer is None else (layer, cache["ptab"])
+
     def gather(name):
-        x = jnp.take(cache[name + "p"], cache["ptab"], axis=0, mode="clip")
+        x = cache[name + "p"].at[at].get(mode="clip")
         if name + "s" in cache:  # (B, pps, kvH, 1, P) -> (B, pps, kvH, P)
-            s = jnp.take(cache[name + "s"], cache["ptab"], axis=0,
-                         mode="clip")[..., 0, :]
+            s = cache[name + "s"].at[at].get(mode="clip")[..., 0, :]
             x = kops.dequantize_kv(x, s, dtype)
         B, pps, kvH, P, hd = x.shape  # head-major pages -> token-major
         return jnp.swapaxes(x, 2, 3).reshape(B, pps * P, kvH, hd).astype(dtype)
@@ -429,7 +446,7 @@ def paged_attention_step(params, cfg: AttnCfg, x, cache, q_pos, valid, *,
 
 
 def ragged_attention_step(params, cfg: AttnCfg, x, cache, slot, q_pos, valid,
-                          *, flash_decode: bool = False):
+                          *, flash_decode: bool = False, layer=None):
     """One ragged serving step: a flat pack of T tokens from arbitrary slots.
 
     x: (1, T, D) hidden pack; slot/q_pos/valid: (T,) per-token slot index,
@@ -439,6 +456,8 @@ def ragged_attention_step(params, cfg: AttnCfg, x, cache, slot, q_pos, valid,
     same machinery as cross-tick causality, so a prefill chunk and the decode
     tokens of other slots coexist in a single program.  Invalid tokens
     scatter nowhere and their outputs are garbage the engine never reads.
+    ``layer``: this layer's index into pool leaves stacked over a scanned
+    stage (``transformer.stage_step_ragged``); None when they are its own.
     """
     T = x.shape[1]
     # (T,kvH,G,hd), (T,kvH,hd) x2
@@ -448,14 +467,13 @@ def ragged_attention_step(params, cfg: AttnCfg, x, cache, slot, q_pos, valid,
     cache = dict(cache)
     B = cache["slen"].shape[0]
     if paged:
-        P = cache["kp"].shape[2]
-        n_pages = cache["kp"].shape[0]
+        n_pages, _, P, _ = cache["kp"].shape[-4:]
         pps = cache["ptab"].shape[-1]
         page_slot = jnp.clip(q_pos // P, 0, pps - 1)
         page = cache["ptab"][slot, page_slot]  # (T,)
         page = jnp.where(valid, page, n_pages)  # OOB -> scatter dropped
         off = q_pos % P
-        _scatter_paged_kv(cache, k_new, v_new, page, off)
+        _scatter_paged_kv(cache, k_new, v_new, page, off, layer)
         Tc = pps * P
         idx = jnp.where(valid, q_pos, Tc)
     else:
@@ -483,12 +501,12 @@ def ragged_attention_step(params, cfg: AttnCfg, x, cache, slot, q_pos, valid,
         lens = jnp.where(valid, q_pos + 1, 0).astype(jnp.int32)
         o = tp_ragged_paged_flash(q, cache["kp"], cache["vp"],
                                   cache["ptab"], slot, lens,
-                                  ks=cache.get("ks"),
-                                  vs=cache.get("vs"))[None]
+                                  ks=cache.get("ks"), vs=cache.get("vs"),
+                                  layer=layer)[None]
         return _out_proj_replicated(params, cfg, o), cache
 
     if paged:
-        k_all, v_all = _gather_paged_kv(cache, q.dtype)
+        k_all, v_all = _gather_paged_kv(cache, q.dtype, layer)
     else:
         k_all, v_all = cache["k"], cache["v"]
     # gather each token's slot context and run T single-query attentions:

@@ -380,12 +380,13 @@ def _ragged_recurrent_roll(dec, p, c, h, s, slot, seq_idx, valid, width: int):
 
 def block_step_ragged(params, cfg: ModelCfg, blk: BlockCfg, x, state, slot,
                       q_pos, seq_idx, valid, *, width: int,
-                      flash_decode: bool = False):
+                      flash_decode: bool = False, layer=None):
     h = rmsnorm(params["mixer_norm"], x, cfg.norm_eps)
     if blk.mixer == "attn":
         m, state = attn.ragged_attention_step(params["mixer"], blk.attn, h,
                                               state, slot, q_pos, valid,
-                                              flash_decode=flash_decode)
+                                              flash_decode=flash_decode,
+                                              layer=layer)
     elif blk.mixer == "mamba":
         m, state = _ragged_recurrent_roll(
             mamba_lib.mamba_decode, params["mixer"], blk.mamba, h, state,
@@ -423,19 +424,33 @@ def stage_step_ragged(params, cfg: ModelCfg, stage: Stage, x, states, slot,
             new_states.append(s)
         return x, new_states
 
-    def body(x, xs):
-        group_params, group_states = xs
-        new_states = []
+    # The shared pool leaves stay stacked over the stage's layers and ride
+    # in the scan's carry; each layer writes and reads them at its own index.
+    # Scanned as xs and re-stacked as ys instead, every layer's pools would
+    # be sliced out and copied back whole on every tick.  Per-slot leaves
+    # and recurrent or windowed state are scanned as before.
+    pools = tuple({n: v for n, v in s.items() if n in attn.POOL_LEAVES}
+                  for s in states)
+    rest = tuple({n: v for n, v in s.items() if n not in attn.POOL_LEAVES}
+                 for s in states)
+
+    def body(carry, xs):
+        x, pools = carry
+        group_params, group_rest, layer = xs
+        new_pools, new_rest = [], []
         for i, blk in enumerate(stage.pattern):
             x, s = block_step_ragged(group_params[i], cfg, blk, x,
-                                     group_states[i], slot, q_pos, seq_idx,
-                                     valid, width=width,
-                                     flash_decode=flash_decode)
-            new_states.append(s)
-        return x, tuple(new_states)
+                                     {**group_rest[i], **pools[i]}, slot,
+                                     q_pos, seq_idx, valid, width=width,
+                                     flash_decode=flash_decode, layer=layer)
+            new_pools.append({n: s[n] for n in pools[i]})
+            new_rest.append({n: s[n] for n in group_rest[i]})
+        return (x, tuple(new_pools)), tuple(new_rest)
 
-    x, new_states = jax.lax.scan(body, x, (tuple(params), tuple(states)))
-    return x, list(new_states)
+    (x, pools), rest = jax.lax.scan(
+        body, (x, pools),
+        (tuple(params), rest, jnp.arange(stage.repeats, dtype=jnp.int32)))
+    return x, [{**r, **p} for r, p in zip(rest, pools)]
 
 
 def reset_stage_slots(stage: Stage, states, init_states, mask, ptab_rows,
@@ -460,7 +475,7 @@ def reset_stage_slots(stage: Stage, states, init_states, mask, ptab_rows,
             # shared pool leaves survive slot churn: KV pages AND their
             # int8 scale rows (a reset must never zero scales a prefix-
             # cached page still dequantizes against)
-            if name in ("kp", "vp", "ks", "vs"):
+            if name in attn.POOL_LEAVES:
                 new[name] = leaf
                 continue
             m = mask.reshape((1,) * lead + (-1,) + (1,) * (leaf.ndim - lead - 1))

@@ -115,10 +115,12 @@ def _head_tp(kvH: int):
     return mesh, head_ax
 
 
-def tp_ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None):
+def tp_ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None,
+                          layer=None):
     """``kernels.ops.ragged_paged_flash`` under the engine mesh: shard_map
-    over the KV-head axis of q and the head-major paged pools (values and
-    int8 scales share the spec); ptab/slot/lens replicate.  Falls back to
+    over the KV-head axis of q and of the head-major paged pools stacked
+    over layers (values and int8 scales share the spec), read at
+    ``layer``; ptab/slot/lens/layer replicate.  Falls back to
     the plain kernel call with no mesh, no "act_kv_heads" rule, or an
     indivisible head count."""
     from repro.kernels import ops as kops
@@ -126,15 +128,19 @@ def tp_ragged_paged_flash(q, kp, vp, ptab, slot, lens, ks=None, vs=None):
     mesh, h = _head_tp(q.shape[1])
     if mesh is None:
         return kops.ragged_paged_flash(q, kp, vp, ptab, slot, lens,
-                                       ks=ks, vs=vs)
-    heads = P(None, h, None, None)  # axis 1 of q and of every pool
+                                       ks=ks, vs=vs, layer=layer)
     pools = (kp, vp) if ks is None else (kp, vp, ks, vs)
+    if layer is None:  # one layer's pools: layer 0 of a 1-layer stack
+        pools, layer = tuple(x[None] for x in pools), 0
+    heads = P(None, h, None, None)  # axis 1 of q
 
-    def local(q, ptab, slot, lens, *pools):
+    def local(q, ptab, slot, lens, layer, *pools):
         return kops.ragged_paged_flash(q, pools[0], pools[1], ptab, slot,
-                                       lens, *pools[2:])
+                                       lens, *pools[2:], layer=layer)
 
     return shard_map(
         local, mesh=mesh,
-        in_specs=(heads, P(), P(), P()) + (heads,) * len(pools),
-        out_specs=heads)(q, ptab, slot, lens, *pools)
+        in_specs=(heads, P(), P(), P(), P())
+        + (P(None, None, h, None, None),) * len(pools),
+        out_specs=heads)(q, ptab, slot, lens,
+                         jnp.asarray(layer, jnp.int32), *pools)

@@ -203,19 +203,54 @@ def _jnp_path(q, kp, vp, ptab, slot, lens, ks=None, vs=None):
     return o[:, 0]
 
 
-@pytest.mark.parametrize("pack,page,pps,kvH,G,dtype", [
-    pytest.param("mixed", 8, 4, 2, 4, jnp.float32, id="8-4"),
-    pytest.param("mixed", 16, 2, 2, 4, jnp.float32, id="16-2"),
-    pytest.param("edges", 16, 12, 2, 6, jnp.bfloat16, id="edges-gqa-bf16"),
-    pytest.param("edges", 8, 20, 2, 6, jnp.float32, id="edges-gqa-page8"),
-    pytest.param("edges", 16, 12, 5, 1, jnp.bfloat16, id="edges-tp-bf16"),
-])
-def test_ragged_paged_flash_allclose(pack, page, pps, kvH, G, dtype):
+def _in_stack(layer, *pools, n_layers=3):
+    """Each pool as plane ``layer`` of an ``n_layers`` stack, the other
+    planes holding its pages rolled (a read of another plane shows)."""
+    return tuple(jnp.stack([p if i == layer else jnp.roll(p, i + 1, axis=0)
+                            for i in range(n_layers)]) for p in pools)
+
+
+def _assert_layer_plane(got, layer, q, kp, vp, ptab, slot, lens, ks=None,
+                        vs=None):
+    """The kernel reading plane ``layer`` of stacked pools gives exactly
+    what it gives on that layer's own pools (``got``)."""
+    if layer is None:
+        return
+    kp, vp = _in_stack(layer, kp, vp)
+    if ks is not None:
+        ks, vs = _in_stack(layer, ks, vs)
+    stacked = ops.ragged_paged_flash(q, kp, vp, jnp.asarray(ptab),
+                                     jnp.asarray(slot), jnp.asarray(lens),
+                                     ks=ks, vs=vs, layer=jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(stacked, np.float32),
+                                  np.asarray(got, np.float32))
+
+
+# a first, a middle and a last layer of a 3-layer stack
+LAYERS = {"layer0": 0, "layer1": 1, "layer2": 2}
+
+
+@pytest.mark.parametrize("pack,page,pps,kvH,G,dtype,layer", [
+    pytest.param("mixed", 8, 4, 2, 4, jnp.float32, None, id="8-4"),
+    pytest.param("mixed", 16, 2, 2, 4, jnp.float32, None, id="16-2"),
+    pytest.param("edges", 16, 12, 2, 6, jnp.bfloat16, None,
+                 id="edges-gqa-bf16"),
+    pytest.param("edges", 8, 20, 2, 6, jnp.float32, None,
+                 id="edges-gqa-page8"),
+    pytest.param("edges", 16, 12, 5, 1, jnp.bfloat16, None,
+                 id="edges-tp-bf16"),
+] + [pytest.param("edges", 16, 12, kvH, G, jnp.bfloat16, layer,
+                  id=f"edges-{name}-bf16-{lid}")
+     for name, kvH, G in (("gqa", 2, 6), ("tp", 5, 1))
+     for lid, layer in LAYERS.items()])
+def test_ragged_paged_flash_allclose(pack, page, pps, kvH, G, dtype, layer):
     """Ragged query packs: per-run slot -> block-table -> pool-row double
     indirection, per-token visible-length masking (intra-pack causality),
     and invalid (lens == 0) tokens yielding zeros.  The two small packs
     check against a gather oracle; the edge packs against the engine's
-    jnp path, over several KV tiles and q tiles."""
+    jnp path, over several KV tiles and q tiles.  With ``layer``, the same
+    pools as one plane of a layer stack, read at that plane's index, give
+    the same output to the bit."""
     hd = 16
     if pack == "edges":
         T = 96 if G == 1 else 40
@@ -235,6 +270,7 @@ def test_ragged_paged_flash_allclose(pack, page, pps, kvH, G, dtype):
         np.testing.assert_allclose(np.asarray(got[~valid], np.float32), 0.0,
                                    atol=1e-6)
         assert not bool(jnp.isnan(got).any())
+        _assert_layer_plane(got, layer, q, kp, vp, ptab, slot, lens)
         return
     B, T = 3, 11
     npages = B * pps
@@ -276,14 +312,20 @@ def test_ragged_paged_flash_allclose(pack, page, pps, kvH, G, dtype):
     assert not bool(jnp.isnan(got).any())
 
 
-@pytest.mark.parametrize("kernel", ["paged", "ragged", "edges-gqa",
-                                    "edges-tp"])
-def test_flash_kernels_fused_dequant_match_fp32_pool(kernel):
+@pytest.mark.parametrize("kernel,layer", [
+    pytest.param(kernel, None, id=kernel)
+    for kernel in ("paged", "ragged", "edges-gqa", "edges-tp")
+] + [pytest.param(kernel, layer, id=f"{kernel}-{lid}")
+     for kernel in ("edges-gqa", "edges-tp")
+     for lid, layer in LAYERS.items()])
+def test_flash_kernels_fused_dequant_match_fp32_pool(kernel, layer):
     """int8 pools: the fused in-VMEM dequant (int8 tile × scale row inside
     the online-softmax loop) must match running the same kernel on the
     dequantized fp32 pool to float tolerance — quantization changes WHERE
     the bytes expand, not the math.  The edge packs also match the jnp
-    path on the int8 pool."""
+    path on the int8 pool, and with ``layer`` the int8 pools and their
+    scales as one plane of a layer stack give the same output to the
+    bit."""
     hd = 16
     if kernel.startswith("edges"):
         kvH, G = (2, 6) if kernel == "edges-gqa" else (5, 1)
@@ -320,6 +362,7 @@ def test_flash_kernels_fused_dequant_match_fp32_pool(kernel):
         path = _jnp_path(q, kp8, vp8, ptab, slot, lens, ks, vs)
         np.testing.assert_allclose(np.asarray(got), np.asarray(path),
                                    rtol=2e-4, atol=2e-4)
+    _assert_layer_plane(got, layer, q, kp8, vp8, ptab, slot, lens, ks, vs)
 
 
 # the chat cell's per-chip shapes (8-token q tiles, 128-position KV tiles)

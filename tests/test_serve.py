@@ -12,6 +12,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.configs import get_config
+from repro.configs.base import Stage
 from repro.models import model as M
 from repro.serve.engine import ServeEngine
 from repro.serve.reference import ReferenceEngine
@@ -613,6 +614,72 @@ def test_flash_ragged_shared_pages_match_jnp(qwen):
         return [r1[u] for u in u1] + [r2[u] for u in u2]
 
     assert run(False) == run(True)
+
+
+def _unrolled(cfg, params):
+    """The same model with each scanned stage laid out as its layers, one
+    block each (a stage of repeats 1, no layer scan): every layer keeps its
+    own pools."""
+    stages, stage_params = [], []
+    for st, sp in zip(cfg.stages, params["stages"]):
+        stages.append(Stage(st.pattern * st.repeats, 1) if st.repeats > 1
+                      else st)
+        stage_params.append(
+            [jax.tree.map(lambda x, r=r: x[r], blk)
+             for r in range(st.repeats) for blk in sp]
+            if st.repeats > 1 else sp)
+    return cfg.replace(stages=tuple(stages)), {**params,
+                                               "stages": stage_params}
+
+
+@pytest.mark.parametrize("arch,flash", [
+    ("qwen2-1.5b", False), ("qwen2-1.5b", True),
+    ("jamba-1.5-large-398b", False),
+], ids=["qwen-jnp", "qwen-flash", "jamba-hybrid"])
+def test_scanned_stage_matches_unrolled_layers(arch, flash):
+    """A scanned stage carries its KV pools stacked through the layer loop
+    and each layer writes and reads them at its own index; the same layers
+    unrolled, each with its own pools, serve token-identical greedy
+    transcripts and hold the same state, leaf by leaf and layer by layer.
+    Attention-only (with a warm prefix hit and a copy-on-write on both
+    engines, and through the kernel) and a hybrid whose mamba state is
+    scanned as before beside the carried pools."""
+    cfg, params = _setup(arch)
+    assert cfg.stages[0].repeats > 1
+    rng = np.random.RandomState(80)
+    a = rng.randint(0, cfg.vocab_size, 26)  # 3 full pages + a partial tail
+    b = a.copy()
+    b[18:] = (b[18:] + 1) % cfg.vocab_size  # diverges mid-page: a COW
+    c = rng.randint(0, cfg.vocab_size, 9)
+
+    def run(cfg, params):
+        eng = ServeEngine(params, cfg, batch_size=2, cache_len=CACHE,
+                          page_size=8, prefill_chunk=16, token_budget=32,
+                          flash_decode=flash)
+        outs = []
+        for wave in ([a, c], [b, a]):
+            uids = [eng.submit(p, max_tokens=4) for p in wave]
+            got = eng.run()
+            outs += [got[u] for u in uids]
+        return outs, eng
+
+    scanned, eng = run(cfg, params)
+    unrolled, flat = run(*_unrolled(cfg, params))
+    assert scanned == unrolled
+    # greedy tokens of an untrained model hardly read the context; the
+    # pools show a layer that wrote to or read from another's plane
+    (blocks,), (flat_blocks,) = eng._state["layers"], flat._state["layers"]
+    for i, blk in enumerate(blocks):
+        for r in range(cfg.stages[0].repeats):
+            twin = flat_blocks[r * len(blocks) + i]
+            assert set(twin) == set(blk)
+            for name, leaf in blk.items():
+                np.testing.assert_array_equal(np.asarray(leaf[r]),
+                                              np.asarray(twin[name]))
+    if arch == "qwen2-1.5b":
+        for e in (eng, flat):
+            assert e.stats["prefix_hits"] >= 2, e.stats
+            assert e.stats["cow_copies"] >= 1, e.stats
 
 
 def test_prefix_cache_disabled_for_hybrid_models(gemma):

@@ -1,4 +1,5 @@
-"""AOT compiles of the serving kernel for a described (not attached) TPU v5e.
+"""AOT compiles of the serving kernel and step for a described (not
+attached) TPU v5e.
 
 Interpret-mode tests check the kernel's numbers; only the chip compiler
 checks its tiling (Mosaic refuses blocks whose last two dims are neither
@@ -6,12 +7,16 @@ checks its tiling (Mosaic refuses blocks whose last two dims are neither
 published widths for one v5e chip — qwen2-1.5b (GQA kvH 2, G 6) and one
 device's share of qwen1.5-4b under a 4-way KV-head split (kvH 5, G 1),
 at a small batch and at each benchmark cell's per-chip batch and pool —
-and assert the Pallas kernel is in the compiled program.
+and assert the Pallas kernel is in the compiled program.  The whole
+ragged serve step is compiled at each cell's shapes too, with depth cut,
+to pin that the KV pools are updated in place: no pool-sized copy, slice
+or update in the program, and every pool leaf aliased to an output.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and every test worker imports this file.
 """
+import dataclasses
 import functools
 import json
 import os
@@ -20,10 +25,18 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro.analysis.contracts import (POOL_LEAVES, _compiled_alias_params,
+                                      _pool_leaf_indices)
+from repro.configs.registry import get_config
 from repro.kernels import flash_attention as fa
+from repro.kernels import ops
+from repro.models import model as M
+from repro.serve.engine import ServeEngine
+from repro.serve.serve_step import STATE_DONATE_ARGNUM, decode_state_specs
 
 HD, PAGE, T, B, CACHE_LEN = 128, 16, 128, 8, 2048
 # the regex by which the benchmark's trace reduction finds the kernel's ops
@@ -38,7 +51,7 @@ TP4 = (16, 2048)  # qwen1.5-4b.tp4-decode, one chip's 5 of 20 KV heads
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -55,9 +68,14 @@ def one_chip():
     except Exception as e:  # noqa: BLE001 — any failure means "no topology"
         jax.config.update("jax_enable_compilation_cache", was)
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _pool_shapes(sharding, kvH, kv_dtype, n_pages=N_PAGES):
@@ -119,3 +137,98 @@ def test_paged_flash_decode_compiles_for_v5e(one_chip):
     _assert_kernel(jax.jit(step).lower(
         q, kp, vp, sds((B, PPS), jnp.int32),
         sds((B,), jnp.int32)).compile())
+
+
+# the ops that may yield a value of a pool's shape in the compiled serve
+# step: none of them moves the pool's bytes (a scatter writes only its rows)
+_POOL_OPS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+             "scatter"}
+_INST = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+
+
+def _pool_sized_ops(text, shapes):
+    """(name, opcode) of every instruction of the compiled program that
+    yields a value of one of ``shapes`` and is not in ``_POOL_OPS``; a
+    fusion counts as its fused computation's root."""
+    roots, comp, found = {}, None, []
+    for ln in text.splitlines():
+        if ln and not ln[0].isspace() and ln.rstrip().endswith("{"):
+            comp = ln.split()[1 if ln.startswith("ENTRY") else 0].lstrip("%")
+            continue
+        m = _INST.match(ln)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        if ln.lstrip().startswith("ROOT"):
+            roots[comp] = op
+        if tuple(int(d) for d in shape.split(",") if d) in shapes:
+            calls = re.search(r"calls=%([\w.\-]+)", ln)
+            found.append((name, op, calls and calls.group(1)))
+    return [(name, op) for name, op, calls in found
+            if op not in _POOL_OPS
+            and not (op == "fusion" and roots.get(calls) == "scatter")]
+
+
+@pytest.mark.parametrize("arch,batch,n_pages,chips", [
+    ("qwen2-1.5b",) + CHAT + (1,),
+    ("qwen1.5-4b",) + TP4 + (4,),
+], ids=["qwen2-1.5b.chat-cell", "qwen1.5-4b.tp4-cell"])
+def test_ragged_serve_step_updates_pools_in_place(topo, monkeypatch, arch,
+                                                  batch, n_pages, chips):
+    """The engine's ragged serve step at the cell's published widths and
+    per-chip pool, two layers deep, on one chip or on the 2x2 mesh by KV
+    head: no instruction but a parameter, a tuple element, a bitcast, the
+    layer loop or an in-place scatter yields a value of a stacked pool's or
+    one layer's pool's shape (a copy, slice or update there moves a whole
+    pool every tick), and every pool leaf of the donated state is aliased
+    to an output."""
+    # the CPU backend would pick the kernel's interpret mode
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = get_config(arch)
+    cfg = dataclasses.replace(cfg, param_dtype="bfloat16", stages=tuple(
+        dataclasses.replace(st, repeats=2) for st in cfg.stages))
+    if chips > 1:
+        mesh = Mesh(np.array(topo.devices[:chips]), ("model",))
+        rep = NamedSharding(mesh, PartitionSpec())
+    else:
+        mesh, rep = None, SingleDeviceSharding(topo.devices[0])
+    eng = ServeEngine(None, cfg, batch_size=batch, cache_len=CACHE_LEN,
+                      page_size=PAGE, max_pages=n_pages, token_budget=T,
+                      prefill_chunk=32, kv_dtype="bfloat16",
+                      flash_decode=True, mesh=mesh)
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    state = jax.eval_shape(lambda p: M.init_paged_state(
+        p, cfg, batch, CACHE_LEN, page_size=PAGE, n_pages=n_pages,
+        window_extra=eng.chunk, kv_dtype="bfloat16"), params)
+    place = lambda x, sharding: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                     sharding=sharding)
+    params = jax.tree.map(lambda x: place(x, rep), params)
+    if mesh is None:
+        state = jax.tree.map(lambda x: place(x, rep), state)
+    else:
+        with eng._ctx():
+            specs = decode_state_specs(state)
+        state = jax.tree.map(lambda x, s: place(x, NamedSharding(mesh, s)),
+                             state, specs,
+                             is_leaf=lambda x: isinstance(x, PartitionSpec))
+    vec = jax.ShapeDtypeStruct((T,), jnp.int32, sharding=rep)
+    args = (params, state, vec, vec, vec, vec,
+            jax.ShapeDtypeStruct((T,), jnp.bool_, sharding=rep),
+            jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=rep))
+    with eng._ctx():
+        compiled = eng._ragged_step.lower(*args).compile()
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+        if path[-1].key in POOL_LEAVES:
+            local = leaf.sharding.shard_shape(leaf.shape)
+            shapes |= {local, local[1:]}
+    assert len(shapes) == 2, shapes
+    assert _pool_sized_ops(text, shapes) == []
+
+    pools = _pool_leaf_indices(args, STATE_DONATE_ARGNUM)
+    assert len(pools) == 2
+    assert set(pools) <= set(_compiled_alias_params(text)), pools
