@@ -137,8 +137,8 @@ def _build_programs(cfg, pshapes, sshapes, dims):
     from repro.models import model as M
     from repro.serve.serve_step import (STATE_DONATE_ARGNUM, make_page_copy,
                                         make_page_gather, make_page_insert,
-                                        make_paged_step, make_ragged_step,
-                                        make_slot_reset, make_spec_rollback)
+                                        make_ragged_step, make_slot_reset,
+                                        make_spec_rollback)
 
     B, chunk, T, pps, spec_k = (dims["B"], dims["chunk"], dims["T"],
                                 dims["pps"], dims["spec_k"])
@@ -153,14 +153,6 @@ def _build_programs(cfg, pshapes, sshapes, dims):
          (pshapes, sshapes, _sds((T,), i32), _sds((T,), i32),
           _sds((T,), i32), _sds((T,), i32), _sds((T,), b_),
           _sds((B, 1 + spec_k), i32)),
-         donate, STATE_DONATE_ARGNUM, True),
-        ("_chunk_step", make_paged_step(cfg, with_logits=False),
-         (pshapes, sshapes, _sds((B, chunk), i32), _sds((B, chunk), i32),
-          _sds((B, chunk), b_)),
-         donate, STATE_DONATE_ARGNUM, True),
-        ("_decode_step", make_paged_step(cfg, with_logits=True),
-         (pshapes, sshapes, _sds((B, 1), i32), _sds((B, 1), i32),
-          _sds((B, 1), b_)),
          donate, STATE_DONATE_ARGNUM, True),
         ("_reset", make_slot_reset(cfg),
          (sshapes, sshapes, _sds((B,), b_), _sds((B, pps), i32),
@@ -225,14 +217,13 @@ _ENGINE_REL = "src/repro/serve/engine.py"
 # attribute: "donate" = the shared (STATE_DONATE_ARGNUM,) tuple, an int =
 # that literal argnum, None = NO donation allowed
 _EXPECTED_JIT_DONATION: Dict[str, Optional[object]] = {
-    "_ragged_step": "donate", "_chunk_step": "donate",
-    "_decode_step": "donate", "_reset": 0, "_copy": 0,
+    "_ragged_step": "donate", "_reset": 0, "_copy": 0,
     "_gather_page": None, "_insert_page": 0, "_spec_rollback": 0,
 }
 # donated-state argument position at each program's CALL sites
 _DONATED_CALL_ARG: Dict[str, int] = {
-    "_ragged_step": 1, "_chunk_step": 1, "_decode_step": 1,
-    "_reset": 0, "_copy": 0, "_insert_page": 0, "_spec_rollback": 0,
+    "_ragged_step": 1, "_reset": 0, "_copy": 0, "_insert_page": 0,
+    "_spec_rollback": 0,
 }
 
 SERVE_PROGRAMS = tuple(_EXPECTED_JIT_DONATION)

@@ -65,8 +65,8 @@ JIT_FACTORY_SITES: Set[Tuple[str, str]] = {
 # hot path, so their arguments must be arrays (or pytrees of arrays), never
 # fresh Python scalars
 JITTED_PROGRAM_ATTRS: Set[str] = {
-    "_ragged_step", "_chunk_step", "_decode_step", "_reset", "_copy",
-    "_gather_page", "_insert_page", "_spec_rollback", "_decode",
+    "_ragged_step", "_reset", "_copy", "_gather_page", "_insert_page",
+    "_spec_rollback", "_decode",
 }
 # movers whose trailing page-id argument must be wrapped as array data
 _PAGE_ARG_MOVERS = {"_gather_page", "_insert_page"}

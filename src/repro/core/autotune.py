@@ -88,8 +88,7 @@ def select_defaults(arch: str, shape_name: str, mesh, **kw) -> Dict:
 # the paper's figures reward flatness across the grid, not one tall corner).
 
 
-# Analytic scheduler model for the two policy-sensitive traffic points the
-# measured A/B (benchmarks/serve_sweep.py:scheduler_ab_scenario) exercises.
+# Analytic scheduler model for two policy-sensitive traffic points.
 # ``residency``: fraction of a shared family prefix still resident when the
 # NEXT family member is admitted, under the A/B's pressure regime (the pool
 # holds roughly half the live prefix families at once): prefix-aware
@@ -160,8 +159,7 @@ def select_serve_defaults(arch: str, *, batch_size: int = 8,
     criterion) — under max-min the scheduler axis is typically decided by
     whichever criterion a policy sacrifices LEAST (slo gives up some warm
     throughput, prefix-aware gives up the interactive jump; fifo gives up
-    both and can never win the axis).  benchmarks/serve_sweep.py records
-    the selection next to the measured rows in BENCH_serve.json.
+    both and can never win the axis).
 
     ``device_counts`` adds the KV-head tensor-parallel axis (ServeEngine
     ``mesh=``): each count is threaded to ``mixed_bound(n_devices=...)``,

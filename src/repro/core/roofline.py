@@ -123,8 +123,6 @@ def decode_bound(cfg, batch: int, context_len: int, hw: HwSpec = V5E,
     ``1 + 4/hd`` bytes per element (values + amortized scales) instead of
     the activation dtype's 2-4 — the decode side of serving is memory-bound
     (the KNL follow-up's regime), so this term is usually the bound.
-    benchmarks/serve_sweep.py scores measured engine throughput against
-    ``tokens_per_s`` from this bound.
 
     ``n_devices`` models KV-head tensor parallelism (serve.engine ``mesh=``):
     each device holds 1/N of the paged KV pools and attends over only its
@@ -206,8 +204,7 @@ def mixed_bound(cfg, n_decode: int, n_prefill: int, context_len: int,
     ``kv_write_bytes`` — the decode-side traffic the int8 pool halves or
     better), ``tokens_per_s`` for the whole pack, and
     ``speedup_vs_two_phase`` — the bound-level ratio against running the
-    same tokens as separate prefill + decode programs.  The serve sweep
-    reports measured ragged throughput against this bound.
+    same tokens as separate prefill + decode programs.
 
     ``n_devices`` models KV-head tensor parallelism exactly as in
     ``decode_bound``: paged-layer attention FLOPs and KV read/write bytes

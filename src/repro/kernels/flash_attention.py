@@ -10,9 +10,9 @@ EXPERIMENTS.md §Perf).
 Layouts: q (BH, S, hd); k, v (BKV, S, hd) with BH = B·kvH·G, BKV = B·kvH.
 Grid = (BH, nq, nk), K innermost.
 
-The paged serving kernel (``ragged_paged_flash``; the two-phase decode
-step calls it with one query row per slot) walks runs of pack tokens and,
-for each, only the KV tiles its length reaches (see its section below).
+The paged serving kernel (``ragged_paged_flash``) walks runs of pack
+tokens and, for each, only the KV tiles its length reaches (see its
+section below).
 It also supports **int8 quantized KV pools**: when the pool dtype is int8,
 per-entry-per-KV-head float32 scale pools (``ks``/``vs``, shape
 (n_pages, kvH, 1, page)) ride in through the same block table, and each

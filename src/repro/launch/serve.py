@@ -1,6 +1,5 @@
 """Serving launcher: batched requests through the ragged token-budget
-engine (``--engine chunked`` runs the PR 1 two-phase paged engine,
-``--engine reference`` the seed lock-step engine, for A/B).
+engine (``--engine reference`` runs the seed lock-step engine instead).
 ``--scheduler`` swaps the admission/packing policy (fifo | prefix-aware |
 slo); with ``slo``, ``--interactive-every N`` marks every Nth request
 priority 1 so the policy has two classes to separate.
@@ -29,7 +28,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="serve the arch's small smoke preset instead of its "
                          "published width")
-    ap.add_argument("--engine", choices=("ragged", "chunked", "reference"),
+    ap.add_argument("--engine", choices=("ragged", "reference"),
                     default="ragged")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch-size", type=int, default=4)
@@ -85,7 +84,6 @@ def main(argv=None):
                              max_pages=args.max_pages,
                              prefill_chunk=args.prefill_chunk,
                              token_budget=args.token_budget,
-                             ragged=args.engine == "ragged",
                              flash_decode=args.flash_decode,
                              prefix_cache=not args.no_prefix_cache,
                              kv_dtype=args.kv_dtype,

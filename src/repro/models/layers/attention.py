@@ -334,7 +334,7 @@ def _paged_masked_attn(q, k, v, kpos, q_pos, window):
 
 def _scatter_paged_kv(cache, k_new, v_new, page, off, layer=None):
     """Scatter new K/V rows into the head-major pools at ``[page, :, off]``
-    — the single write path shared by the two-phase and ragged steps.
+    — the serving step's single write path.
     k_new/v_new: (..., kvH, hd) with ``page``/``off`` of shape (...).
     ``layer`` indexes pools stacked over a scanned stage's layers, (L,
     n_pages, ...); None for one layer's pools.  int8 pools quantize on
@@ -381,68 +381,6 @@ def _gather_paged_kv(cache, dtype, layer=None):
         return jnp.swapaxes(x, 2, 3).reshape(B, pps * P, kvH, hd).astype(dtype)
 
     return gather("k"), gather("v")
-
-
-def paged_attention_step(params, cfg: AttnCfg, x, cache, q_pos, valid, *,
-                         flash_decode: bool = False):
-    """One serving step against the paged cache: writes the C incoming
-    tokens, then attends over everything written so far.
-
-    x: (B, C, D) — C == 1 is a decode tick, C > 1 a prefill chunk.
-    q_pos: (B, C) absolute positions (per-slot); valid: (B, C) marks real
-    tokens (False rows/tails: no cache write, output ignored by the engine).
-    """
-    B, C, _ = x.shape
-    # (B,C,kvH,G,hd), (B,C,kvH,hd) x2
-    q, k_new, v_new = _serving_qkv(params, cfg, x, q_pos)
-
-    b_iota = jnp.broadcast_to(jnp.arange(B)[:, None], (B, C))
-    paged = "kp" in cache
-    cache = dict(cache)
-    if paged:
-        P = cache["kp"].shape[2]
-        n_pages = cache["kp"].shape[0]
-        pps = cache["ptab"].shape[-1]
-        page_slot = jnp.clip(q_pos // P, 0, pps - 1)
-        page = jnp.take_along_axis(cache["ptab"], page_slot, axis=1)
-        page = jnp.where(valid, page, n_pages)  # OOB -> scatter dropped
-        off = q_pos % P
-        _scatter_paged_kv(cache, k_new, v_new, page, off)
-        T = pps * P
-        idx = jnp.where(valid, q_pos, T)
-    else:
-        cap = cache["k"].shape[1]
-        # chunk positions are contiguous per row: when C > cap the circular
-        # buffer wraps within one scatter, so keep only the last ``cap``
-        # writes per row (duplicate scatter indices have unspecified order)
-        row_max = jnp.max(jnp.where(valid, q_pos, -1), axis=1, keepdims=True)
-        keep = valid & (q_pos > row_max - cap)
-        idx = jnp.where(keep, q_pos % cap, cap)
-        cache["k"] = cache["k"].at[b_iota, idx].set(k_new, mode="drop")
-        cache["v"] = cache["v"].at[b_iota, idx].set(v_new, mode="drop")
-        T = cap
-    cache["kpos"] = cache["kpos"].at[b_iota, idx].set(q_pos, mode="drop")
-    cache["slen"] = jnp.maximum(
-        cache["slen"], jnp.max(jnp.where(valid, q_pos + 1, 0), axis=1))
-
-    if paged and flash_decode and C == 1:
-        # TP entry point: shard_maps the Pallas kernel over the KV-head axis
-        # under the serving mesh, plain kernel call otherwise
-        # under the serving mesh, plain kernel call otherwise; one query row
-        # per slot is the ragged kernel with slot = arange(B)
-        from repro.serve.decode_attention import tp_ragged_paged_flash
-
-        o = tp_ragged_paged_flash(q[:, 0], cache["kp"], cache["vp"],
-                                  cache["ptab"], jnp.arange(B, dtype=jnp.int32),
-                                  cache["slen"], ks=cache.get("ks"),
-                                  vs=cache.get("vs"))[:, None]
-    elif paged:
-        k, v = _gather_paged_kv(cache, q.dtype)
-        o = _paged_masked_attn(q, k, v, cache["kpos"], q_pos, cfg.window)
-    else:
-        o = _paged_masked_attn(q, cache["k"], cache["v"], cache["kpos"],
-                               q_pos, cfg.window)
-    return _out_proj_replicated(params, cfg, o), cache
 
 
 def ragged_attention_step(params, cfg: AttnCfg, x, cache, slot, q_pos, valid,

@@ -168,33 +168,6 @@ def init_paged_state(params, cfg: ModelCfg, batch: int, cache_len: int, *,
     return {"layers": states}
 
 
-def paged_step(params, cfg: ModelCfg, state, tokens, q_pos, valid, *,
-               with_logits: bool = True, flash_decode: bool = False):
-    """One serving step: C tokens per slot at per-slot absolute positions.
-
-    tokens/q_pos/valid: (B, C).  C == 1 is a decode tick (returns logits);
-    C > 1 is a prefill chunk (``with_logits=False`` skips the LM head — the
-    engine only samples from decode ticks).  Invalid entries write nothing
-    and leave recurrent state untouched, so idle slots ride along for free.
-    """
-    dt = jnp.dtype(cfg.dtype)
-    x = emb.embed_tokens(params["embed"], tokens, dt)
-    if cfg.abs_pos == "sinusoidal":
-        x = x + emb.sinusoidal_at(q_pos, cfg.d_model, dt)
-    new_layers = []
-    for st, sp, ss in zip(cfg.stages, params["stages"], state["layers"]):
-        x, ns = tfm.stage_step_paged(sp, cfg, st, x, ss, q_pos, valid,
-                                     flash_decode=flash_decode)
-        new_layers.append(ns)
-    new_state = {"layers": new_layers}
-    if not with_logits:
-        return None, new_state
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    tied = params["embed"]["tok_embed"] if cfg.tie_embeddings else None
-    logits = emb.logits_from_hidden(params.get("head", {}), x, tied_embed=tied)
-    return logits, new_state
-
-
 def ragged_step(params, cfg: ModelCfg, state, tokens, slot, q_pos, seq_idx,
                 valid, logit_idx, *, width: int,
                 flash_decode: bool = False):

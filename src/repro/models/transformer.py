@@ -286,10 +286,24 @@ def init_stage_state_paged(params, cfg: ModelCfg, stage: Stage, batch: int,
         for s in one]
 
 
-def _masked_recurrent_roll(dec, p, c, h, s, valid):
-    """Scan a single-step recurrent decode over the C chunk positions,
-    advancing state only where ``valid`` — pad tails and idle slots keep
-    their state bit-identical.  h: (B,C,D), valid: (B,C)."""
+def _ragged_recurrent_roll(dec, p, c, h, s, slot, seq_idx, valid, width: int):
+    """Ragged pack -> per-slot dense -> masked roll -> scatter back.
+
+    Recurrent mixers must consume a slot's tokens in position order, but the
+    pack interleaves slots.  The scheduler guarantees (a) at most ``width``
+    tokens per slot per pack and (b) in-pack position order, so a scatter by
+    (slot, intra-slot ordinal) into a dense (B, width) layout lets a scan of
+    the single-step decode run over the width columns, advancing state only
+    where valid — pad tails and idle slots keep their state bit-identical;
+    outputs gather back by the same indices.  h: (1,T,D); slot/seq_idx/
+    valid: (T,).
+    """
+    B = next(iter(jax.tree.leaves(s))).shape[0]
+    h0 = h[0]  # (T,D)
+    col = jnp.where(valid, seq_idx, width)
+    dense = jnp.zeros((B, width, h0.shape[-1]), h0.dtype)
+    dense = dense.at[slot, col].set(h0, mode="drop")
+    vdense = jnp.zeros((B, width), bool).at[slot, col].set(valid, mode="drop")
 
     def step(s, inp):
         h_t, v_t = inp
@@ -300,80 +314,8 @@ def _masked_recurrent_roll(dec, p, c, h, s, valid):
         return s, y[:, 0]
 
     s, ys = jax.lax.scan(
-        step, s, (jnp.moveaxis(h, 1, 0), jnp.moveaxis(valid, 1, 0)))
-    return jnp.moveaxis(ys, 0, 1), s
-
-
-def block_step_paged(params, cfg: ModelCfg, blk: BlockCfg, x, state, q_pos,
-                     valid, *, flash_decode: bool = False):
-    h = rmsnorm(params["mixer_norm"], x, cfg.norm_eps)
-    if blk.mixer == "attn":
-        m, state = attn.paged_attention_step(params["mixer"], blk.attn, h,
-                                             state, q_pos, valid,
-                                             flash_decode=flash_decode)
-    elif blk.mixer == "mamba":
-        m, state = _masked_recurrent_roll(
-            mamba_lib.mamba_decode, params["mixer"], blk.mamba, h, state, valid)
-    elif blk.mixer == "mlstm":
-        m, state = _masked_recurrent_roll(
-            xlstm_lib.mlstm_decode, params["mixer"], blk.xlstm, h, state, valid)
-    elif blk.mixer == "slstm":
-        m, state = _masked_recurrent_roll(
-            xlstm_lib.slstm_decode, params["mixer"], blk.xlstm, h, state, valid)
-    else:
-        raise NotImplementedError(f"paged serving: unsupported mixer {blk.mixer}")
-    x = x + m
-    if blk.ffn is not None:
-        h = rmsnorm(params["ffn_norm"], x, cfg.norm_eps)
-        if blk.ffn == "mlp":
-            f = mlp_fwd(params["ffn"], blk.mlp, h)
-        else:
-            f, _ = moe_fwd(params["ffn"], blk.moe, h)
-        x = x + f
-    return x, state
-
-
-def stage_step_paged(params, cfg: ModelCfg, stage: Stage, x, states, q_pos,
-                     valid, *, flash_decode: bool = False):
-    if stage.repeats == 1:
-        new_states = []
-        for i, blk in enumerate(stage.pattern):
-            x, s = block_step_paged(params[i], cfg, blk, x, states[i], q_pos,
-                                    valid, flash_decode=flash_decode)
-            new_states.append(s)
-        return x, new_states
-
-    def body(x, xs):
-        group_params, group_states = xs
-        new_states = []
-        for i, blk in enumerate(stage.pattern):
-            x, s = block_step_paged(group_params[i], cfg, blk, x,
-                                    group_states[i], q_pos, valid,
-                                    flash_decode=flash_decode)
-            new_states.append(s)
-        return x, tuple(new_states)
-
-    x, new_states = jax.lax.scan(body, x, (tuple(params), tuple(states)))
-    return x, list(new_states)
-
-
-def _ragged_recurrent_roll(dec, p, c, h, s, slot, seq_idx, valid, width: int):
-    """Ragged pack -> per-slot dense -> masked roll -> scatter back.
-
-    Recurrent mixers must consume a slot's tokens in position order, but the
-    pack interleaves slots.  The scheduler guarantees (a) at most ``width``
-    tokens per slot per pack and (b) in-pack position order, so a scatter by
-    (slot, intra-slot ordinal) into a dense (B, width) layout makes the
-    existing masked roll apply unchanged; outputs gather back by the same
-    indices.  h: (1,T,D); slot/seq_idx/valid: (T,).
-    """
-    B = next(iter(jax.tree.leaves(s))).shape[0]
-    h0 = h[0]  # (T,D)
-    col = jnp.where(valid, seq_idx, width)
-    dense = jnp.zeros((B, width, h0.shape[-1]), h0.dtype)
-    dense = dense.at[slot, col].set(h0, mode="drop")
-    vdense = jnp.zeros((B, width), bool).at[slot, col].set(valid, mode="drop")
-    y_dense, s = _masked_recurrent_roll(dec, p, c, dense, s, vdense)
+        step, s, (jnp.moveaxis(dense, 1, 0), jnp.moveaxis(vdense, 1, 0)))
+    y_dense = jnp.moveaxis(ys, 0, 1)
     y = y_dense[slot, jnp.minimum(col, width - 1)]  # (T,D); invalid rows junk
     return y[None], s
 

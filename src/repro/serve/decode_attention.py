@@ -12,9 +12,9 @@ One of the two decode-attention paths under ``serve/``:
   32k–512k contexts serveable at all (an all-gathered KV would be GBs per
   layer per step).
 - **The paged per-slot path** — ``models.layers.attention
-  .paged_attention_step`` / ``ragged_attention_step`` (jnp gather over
-  block tables) and ``kernels.flash_attention.ragged_paged_flash`` (Pallas,
-  block table as scalar prefetch), driven by ``serve.engine.ServeEngine``.
+  .ragged_attention_step`` (jnp gather over block tables) and
+  ``kernels.flash_attention.ragged_paged_flash`` (Pallas, block table as
+  scalar prefetch), driven by ``serve.engine.ServeEngine``.
   Use that for mixed-length continuous batching; use this one when the KV
   of a single sequence outgrows one device.
 

@@ -127,9 +127,6 @@ from PR 1–4, and the reason any policy mix stays near peak):
     correctness: completed requests stay token-identical and both tiers
     drain to zero leaked pages (tests/test_chaos.py holds the line).
 
-The PR 1 two-phase path is kept behind ``ragged=False`` for A/B (admission
-policy applies there too; pack ordering is a ragged-path concept).
-``benchmarks/serve_sweep.py`` carries the engine and scheduler A/Bs;
 ``core.autotune.select_serve_defaults`` emits the tuned-once serving config
 (token_budget × prefill_chunk × page_size × kv_dtype × scheduler).
 
@@ -182,8 +179,8 @@ from repro.serve import spans
 from repro.serve.scheduler import EngineView, Scheduler, make_scheduler
 from repro.serve.serve_step import (STATE_DONATE_ARGNUM, make_page_copy,
                                     make_page_gather, make_page_insert,
-                                    make_paged_step, make_ragged_step,
-                                    make_slot_reset, make_spec_rollback)
+                                    make_ragged_step, make_slot_reset,
+                                    make_spec_rollback)
 
 from repro.core.roofline import KV_ITEMSIZE
 
@@ -226,8 +223,8 @@ class ServeEngine:
                  cache_len: int = 256, page_size: int = 16,
                  max_pages: Optional[int] = None, prefill_chunk: int = 32,
                  token_budget: int = 128, greedy: bool = True,
-                 ragged: bool = True, flash_decode: bool = False,
-                 prefix_cache: bool = True, kv_dtype: Optional[str] = None,
+                 flash_decode: bool = False, prefix_cache: bool = True,
+                 kv_dtype: Optional[str] = None,
                  scheduler=None, mesh=None, host_pages: int = 0,
                  spec_k: int = 0, preempt: bool = True,
                  max_queue: Optional[int] = None, fault_injector=None):
@@ -264,7 +261,6 @@ class ServeEngine:
         self.chunk = prefill_chunk
         self.budget = token_budget
         self.greedy = greedy
-        self.ragged = ragged
         # workload-policy layer: admission + pack ordering (None/"fifo" is
         # the PR 1-4 behavior, bit-identical).  Policies that keep the
         # protocol's identity orders (fifo: all three; prefix-aware: the
@@ -281,9 +277,6 @@ class ServeEngine:
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         if spec_k and not isinstance(self.scheduler, SpeculativeScheduler):
-            if not ragged:
-                raise ValueError("speculative decoding needs the ragged "
-                                 "path (spec_k > 0 with ragged=False)")
             self.scheduler = SpeculativeScheduler(self.scheduler,
                                                   spec_k=spec_k)
         self.scheduler_name = getattr(self.scheduler, "name",
@@ -308,7 +301,7 @@ class ServeEngine:
         if self.kv_dtype not in KV_ITEMSIZE:
             raise ValueError(f"unsupported kv_dtype {self.kv_dtype!r} "
                              f"(pick from {sorted(KV_ITEMSIZE)})")
-        if ragged and token_budget < batch_size:
+        if token_budget < batch_size:
             raise ValueError(
                 f"token_budget={token_budget} < batch_size={batch_size}: "
                 "every decoding slot needs one pack entry per tick")
@@ -338,7 +331,7 @@ class ServeEngine:
         # replays history through the ragged pack, and a parked page only
         # captures the ENTIRE per-position state when every layer is paged
         # global attention (recurrent / windowed state has no page to park)
-        self.preempt = bool(preempt) and ragged and all_global
+        self.preempt = bool(preempt) and all_global
         if max_queue is not None and max_queue < 1:
             raise ValueError(f"max_queue must be >= 1, got {max_queue}")
         self.max_queue = max_queue
@@ -387,8 +380,8 @@ class ServeEngine:
         self._uid = 0
         self.completion_order: List[int] = []
         self._state = None  # persistent: the pool doubles as the prefix cache
-        self._stats = {"chunk_ticks": 0, "decode_ticks": 0, "ragged_ticks": 0,
-                       "ticks": 0, "packed_tokens": 0, "traces": 0,
+        self._stats = {"ragged_ticks": 0, "ticks": 0, "packed_tokens": 0,
+                       "traces": 0,
                        # the ragged kernel's work: runs and KV tiles one
                        # layer's call visits, summed over ragged ticks
                        "attn_runs": 0, "attn_kv_tiles": 0,
@@ -484,10 +477,6 @@ class ServeEngine:
                 ragged_visits, kvH=kvH, G=a.num_heads // a.num_kv_heads,
                 hd=a.head_dim, page=page_size, pps=self.pps,
                 kv_dtype=self.kv_dtype)
-        step = lambda wl: make_paged_step(cfg, with_logits=wl,
-                                          flash_decode=flash_decode)
-        self._chunk_step = jax.jit(step(False), donate_argnums=donate)
-        self._decode_step = jax.jit(step(True), donate_argnums=donate)
         # control-plane programs (admission reset, COW page copy) — separate
         # from the serve path, each traced at most once
         self._reset = jax.jit(make_slot_reset(cfg), donate_argnums=(0,))
@@ -1364,57 +1353,6 @@ class ServeEngine:
                         self._stats["spec_rollbacks"] += int(mask.sum())
         return state, results
 
-    # -- legacy two-phase path (PR 1, kept behind ragged=False) -----------
-    def _prefill_tick(self, state):
-        """Advance every slot with outstanding prompt tokens by one chunk —
-        a single batched (B, chunk) call with per-slot positions."""
-        C = self.chunk
-        tokens = np.zeros((self.B, C), np.int32)
-        q_pos = np.zeros((self.B, C), np.int32)
-        valid = np.zeros((self.B, C), bool)
-        for b, s in enumerate(self.slots):
-            if s is None:
-                continue
-            L = len(s.prefill_tokens)
-            if s.fill >= L:
-                continue
-            n = min(C, L - s.fill)
-            tokens[b, :n] = s.prefill_tokens[s.fill:s.fill + n]
-            q_pos[b] = s.fill + np.arange(C)
-            valid[b, :n] = True
-            s.fill += n
-            self._index_filled_pages(s)
-            if s.fill >= L:
-                s.pos = L
-                s.last_tok = (s.resume_tok if s.resume_tok is not None
-                              else int(s.prefill_tokens[-1]))
-        _, state = self._chunk_step(self.params, state, tokens, q_pos, valid)
-        self._stats["chunk_ticks"] += 1
-        return state
-
-    def _decode_tick(self, state):
-        tokens = np.zeros((self.B, 1), np.int32)
-        q_pos = np.zeros((self.B, 1), np.int32)
-        valid = np.zeros((self.B, 1), bool)
-        for b, s in enumerate(self.slots):
-            if s is None:
-                continue
-            tokens[b, 0] = s.last_tok
-            q_pos[b, 0] = s.pos
-            valid[b, 0] = True
-        logits, state = self._decode_step(self.params, state, tokens, q_pos,
-                                          valid)
-        rows = np.asarray(logits[:, -1])
-        self._stats["decode_ticks"] += 1
-        results: Dict[int, List[int]] = {}
-        for b, s in enumerate(self.slots):
-            if s is None:
-                continue
-            self._finish_token(b, self._sample(s.req, rows[b],
-                                               len(s.req.out_tokens)),
-                               results)
-        return state, results
-
     # -- driving ----------------------------------------------------------
     @property
     def idle(self) -> bool:
@@ -1423,9 +1361,9 @@ class ServeEngine:
 
     def _ctx(self):
         """Ambient mesh + serve rules for every trace/execute of the
-        compiled programs (no-op without ``mesh=``).  All four device
-        programs — the serve step, COW copy, slot reset, and the two-phase
-        legacy steps — must trace under the SAME context so the lshard
+        compiled programs (no-op without ``mesh=``).  Every device program
+        — the serve step, slot reset, COW copy, page movers and spec
+        rollback — must trace under the SAME context so the lshard
         constraints in the model and the shard_map kernel wrappers see the
         KV-head rule; the sharded state then keeps every program's layout
         consistent via input propagation."""
@@ -1483,7 +1421,7 @@ class ServeEngine:
     def tick(self) -> Dict[int, List[int]]:
         """One scheduling tick: admit from the queue, pack, run one program
         step.  Returns the requests that finished this tick ({uid: tokens}).
-        Public so continuous-arrival drivers (benchmarks/serve_sweep.py) and
+        Public so continuous-arrival drivers (``bench/drive.py``) and
         ``RequestHandle.tokens()`` iterators can interleave ``submit`` with
         serving instead of draining a batch."""
         with spans.step(spans.TICK, step_num=self._stats["ticks"]), \
@@ -1505,13 +1443,7 @@ class ServeEngine:
             had_prefill = any(s is not None
                               and s.fill < len(s.prefill_tokens)
                               for s in self.slots)
-            results: Dict[int, List[int]] = {}
-            if self.ragged:
-                self._state, results = self._ragged_tick(self._state)
-            elif had_prefill:
-                self._state = self._prefill_tick(self._state)
-            elif any(s is not None for s in self.slots):
-                self._state, results = self._decode_tick(self._state)
+            self._state, results = self._ragged_tick(self._state)
             self._stats["ticks"] += 1
             self.tick_log.append((had_prefill, spans.now()))
             return results

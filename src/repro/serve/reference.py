@@ -7,7 +7,7 @@ prompt length, sequential host round-trips), every slot pays ``cache_len``
 KV regardless of sequence length, and positions are lock-step across slots
 (shared ``k_pos``/``pos``) so only equal-length prompt waves decode
 correctly.  Tests pin the paged engine token-for-token against this engine
-on equal-length traffic; ``benchmarks/serve_sweep.py`` scores the speedup.
+on equal-length traffic.
 """
 from __future__ import annotations
 

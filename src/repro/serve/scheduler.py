@@ -76,10 +76,9 @@ Policies:
   literally a packing policy — it composes with every admission/ordering
   policy and inherits the one-trace and no-OOM guarantees unchanged.
 
-``benchmarks/serve_sweep.py:scheduler_ab_scenario`` A/Bs the policies on mixed
-shared-prefix Poisson traffic; ``core.autotune.select_serve_defaults``
-carries a ``scheduler`` axis so the tuned-once serving config names its
-policy alongside token_budget / page_size / kv_dtype.
+``core.autotune.select_serve_defaults`` carries a ``scheduler`` axis so the
+tuned-once serving config names its policy alongside token_budget /
+page_size / kv_dtype.
 """
 from __future__ import annotations
 

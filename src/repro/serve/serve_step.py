@@ -1,11 +1,9 @@
 """The jit-compiled serving steps + state sharding rules.
 
-Three step builders live here:
+Two step builders live here:
 
 - ``make_serve_step`` — the legacy lock-step decode step (one token per
   slot, shared positions; kept for the reference engine path).
-- ``make_paged_step`` — the two-phase path's chunk and decode steps
-  (``ServeEngine(ragged=False)``).
 - ``make_ragged_step`` — the serving engine's ONE compiled program: a flat
   (T,) token pack in which every entry carries its own (slot, position,
   validity), so any mix of prefill-chunk tokens and decode tokens runs
@@ -88,8 +86,6 @@ STATE_DONATE_ARGNUM = 1
 # reduction of it tell the programs apart; of these names only the serve
 # step's holds "wrapper".
 SERVE_STEP = "serve_step_wrapper"
-CHUNK_STEP = "paged_chunk_step"
-DECODE_STEP = "paged_decode_step"
 SLOT_RESET = "slot_reset"
 PAGE_COPY = "cow_page_copy"
 PAGE_GATHER = "page_gather"
@@ -130,20 +126,6 @@ def make_ragged_step(cfg: ModelCfg, *, width: int, flash_decode: bool = False):
                              flash_decode=flash_decode)
 
     return _named(ragged_step, SERVE_STEP)
-
-
-def make_paged_step(cfg: ModelCfg, *, with_logits: bool,
-                    flash_decode: bool = False):
-    """The two-phase path's steps (``ServeEngine(ragged=False)``):
-    ``f(params, state, tokens, q_pos, valid) -> (logits, state)`` over
-    (B, C) chunks, the chunk step without logits and the decode step with
-    them (see ``models.model.paged_step``).  Jit with state donation."""
-    def paged_step(params, state, tokens, q_pos, valid):
-        return M.paged_step(params, cfg, state, tokens, q_pos, valid,
-                            with_logits=with_logits,
-                            flash_decode=flash_decode)
-
-    return _named(paged_step, DECODE_STEP if with_logits else CHUNK_STEP)
 
 
 def make_slot_reset(cfg: ModelCfg):

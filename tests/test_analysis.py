@@ -133,8 +133,8 @@ def test_donation_ast_layers_catch_drift(tmp_path):
 
     src = contracts._ENGINE_PATH.read_text()
     broken = src.replace(
-        "self._chunk_step = jax.jit(step(False), donate_argnums=donate)",
-        "self._chunk_step = jax.jit(step(False))")
+        "self._reset = jax.jit(make_slot_reset(cfg), donate_argnums=(0,))",
+        "self._reset = jax.jit(make_slot_reset(cfg))")
     assert broken != src
     bad = tmp_path / "engine.py"
     bad.write_text(broken)
@@ -144,7 +144,7 @@ def test_donation_ast_layers_catch_drift(tmp_path):
         findings, _ = contracts.check_contracts(compile_programs=False)
     finally:
         contracts._ENGINE_PATH = orig
-    assert any(f.rule == "donation-contract" and "_chunk_step" in f.message
+    assert any(f.rule == "donation-contract" and "_reset" in f.message
                for f in findings)
 
 

@@ -81,10 +81,11 @@ def test_chunked_equals_full():
 @pytest.mark.parametrize("window,chunk", [(None, 6), (8, 6), (8, 24), (8, 1)])
 def test_paged_chunked_prefill_matches_fwd_oracle(window, chunk):
     """Numerical oracle for the serving prefill path: feeding a prompt
-    through paged_attention_step in C-token chunks must reproduce
-    attention_fwd's full-sequence outputs at every position — including
-    windowed layers where a chunk write evicts circular-buffer entries
-    (window_extra = C-1 keeps every in-window key resident)."""
+    through ragged_attention_step in C-token chunks (each tick one pack of
+    every slot's next chunk) must reproduce attention_fwd's full-sequence
+    outputs at every position — including windowed layers where a chunk
+    write evicts circular-buffer entries (window_extra = C-1 keeps every
+    in-window key resident)."""
     cfg, p = _mk_attn(window=window)
     B, S = 2, 24
     x = jax.random.normal(KEY, (B, S, 32), jnp.float32)
@@ -97,10 +98,12 @@ def test_paged_chunked_prefill_matches_fwd_oracle(window, chunk):
     outs = []
     for c0 in range(0, S, chunk):
         C = min(chunk, S - c0)
-        q_pos = jnp.broadcast_to(c0 + jnp.arange(C), (B, C))
-        o, cache = A.paged_attention_step(
-            p, cfg, x[:, c0:c0 + C], cache, q_pos, jnp.ones((B, C), bool))
-        outs.append(o)
+        slot = jnp.repeat(jnp.arange(B, dtype=jnp.int32), C)
+        q_pos = jnp.tile(c0 + jnp.arange(C, dtype=jnp.int32), B)
+        o, cache = A.ragged_attention_step(
+            p, cfg, x[:, c0:c0 + C].reshape(1, B * C, -1), cache, slot,
+            q_pos, jnp.ones((B * C,), bool))
+        outs.append(o.reshape(B, C, -1))
     got = jnp.concatenate(outs, axis=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-5)

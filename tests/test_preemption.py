@@ -218,6 +218,35 @@ def test_preempt_off_stalls_instead(qwen):
     assert _leak_free(eng)
 
 
+@pytest.mark.parametrize("preempt", [True, False], ids=["preempt", "stall"])
+def test_preemption_meets_a_deadline_that_stalling_misses(qwen, preempt):
+    """An interactive chat with a tight deadline arrives while a hog holds
+    the only slot and the whole pool: preemption serves it inside its
+    deadline, exactly; stalling queues it behind the hog until it expires
+    un-served.  Counted in ticks, so it holds on any backend."""
+    cfg, params = qwen
+    want_hog, want_chat = _solo_transcripts(params, cfg)
+    hog, chat = _prompts(cfg, [16, 6])
+    eng = _engine(params, cfg, max_pages=4, host_pages=6, scheduler="slo",
+                  preempt=preempt)
+    h_hog = eng.submit(hog, max_tokens=16)
+    for _ in range(4):
+        eng.tick()
+    h_chat = eng.submit(chat, max_tokens=3, priority=1, deadline_ticks=6)
+    res = eng.run()
+    assert res[h_hog] == want_hog
+    if preempt:
+        assert h_chat.result() == want_chat
+        assert eng.stats["deadline_expired"] == 0
+    else:
+        with pytest.raises(DeadlineExceeded) as exc:
+            h_chat.result()
+        assert exc.value.tokens == []
+        assert eng.stats["deadline_expired"] == 1
+    assert eng.stats["traces"] == 1
+    assert _leak_free(eng)
+
+
 def test_equal_priority_never_preempts(qwen):
     cfg, params = qwen
     # strict-priority guard: a same-class backlog waits, it never thrashes

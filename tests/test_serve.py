@@ -192,16 +192,6 @@ def test_exactly_one_program_for_any_traffic_mix(qwen):
     assert cache_size == 1
 
 
-def test_ragged_matches_chunked_two_phase(qwen):
-    """A/B: the ragged engine and the PR 1 two-phase engine (ragged=False)
-    emit identical greedy tokens on identical traffic."""
-    cfg, params = qwen
-    prompts = _prompts(cfg, [26, 9, 17, 5], seed=24)
-    _, u1, r1 = _serve(cfg, params, prompts, ragged=True)
-    _, u2, r2 = _serve(cfg, params, prompts, ragged=False)
-    assert [r1[u] for u in u1] == [r2[u] for u in u2]
-
-
 @settings(max_examples=5, deadline=None)
 @given(budget=st.sampled_from([8, 24, 64]),
        chunk=st.sampled_from([4, 8, 16]),
@@ -221,30 +211,23 @@ def test_ragged_property_over_budget_chunk_page(qwen, budget, chunk, page):
 
 def test_decode_never_stalls_during_prefill(qwen):
     """The head-of-line fix: while a long document prefills, a decoding
-    chat slot emits a token EVERY tick in the ragged engine; the two-phase
-    engine stalls it for the whole prefill burst."""
+    chat slot emits a token EVERY tick — decode tokens are packed first,
+    the prefill chunk takes the leftover budget."""
     cfg, params = qwen
     [chat] = _prompts(cfg, [4], seed=30)
     [filler] = _prompts(cfg, [4], seed=31)
     [doc] = _prompts(cfg, [56], seed=32)
 
-    def run(ragged):
-        eng = ServeEngine(params, cfg, batch_size=2, cache_len=CACHE,
-                          page_size=8, prefill_chunk=8, token_budget=16,
-                          ragged=ragged)
-        u_chat = eng.submit(chat, max_tokens=12)
-        eng.submit(filler, max_tokens=1)  # frees its slot for the doc
-        eng.submit(doc, max_tokens=2)  # admitted mid-chat-decode
-        eng.run()
-        ticks = [t for uid, t, _ in eng.token_log if uid == u_chat]
-        return eng, ticks
-
-    eng, ticks = run(True)
+    eng = ServeEngine(params, cfg, batch_size=2, cache_len=CACHE,
+                      page_size=8, prefill_chunk=8, token_budget=16)
+    u_chat = eng.submit(chat, max_tokens=12)
+    eng.submit(filler, max_tokens=1)  # frees its slot for the doc
+    eng.submit(doc, max_tokens=2)  # admitted mid-chat-decode
+    eng.run()
+    ticks = [t for uid, t, _ in eng.token_log if uid == u_chat]
     assert max(np.diff(ticks)) == 1  # consecutive ticks, no stall
     # ...and the doc really was prefilling during several of those ticks
     assert sum(eng.tick_log[t][0] for t in ticks) >= 3
-    _, ticks_chunked = run(False)
-    assert max(np.diff(ticks_chunked)) > 1  # the two-phase engine stalls
 
 
 # ---------------------------------------------------------------------------

@@ -127,21 +127,3 @@ def test_token_identity_flash_kernel_path(multidevice, kv_dtype):
         a, b = np.asarray(l1[uid]), np.asarray(l4[uid])
         assert np.array_equal(a, b), (uid, float(np.max(np.abs(a - b))))
     assert p1 == p4, [k for k in p1 if p1[k] != p4.get(k)]
-
-
-def test_sharded_scenario_refuses_a_tpu_parent(monkeypatch):
-    """The forked-device scenario measures CPU children; from a parent on
-    a TPU backend it must refuse (that parent holds the chip) and point to
-    the in-process four-chip path instead of reporting CPU numbers."""
-    import importlib.util
-    from pathlib import Path
-
-    import jax
-
-    path = Path(__file__).resolve().parents[1] / "benchmarks" / "serve_sweep.py"
-    spec = importlib.util.spec_from_file_location("serve_sweep", path)
-    sweep = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sweep)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(RuntimeError, match="chip_smoke.py --four-chips"):
-        sweep.sharded_serve_scenario()

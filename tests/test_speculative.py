@@ -134,8 +134,6 @@ def test_engine_validates_spec_k(qwen):
     cfg, params = qwen
     with pytest.raises(ValueError):
         _engine(params, cfg, spec_k=-1)
-    with pytest.raises(ValueError):
-        _engine(params, cfg, spec_k=2, ragged=False)
 
 
 # ---------------------------------------------------------------------------
