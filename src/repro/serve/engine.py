@@ -187,6 +187,27 @@ from repro.core.roofline import KV_ITEMSIZE
 __all__ = ["ServeEngine", "kv_page_bytes", "kv_bytes_per_token"]
 
 
+class _LogitsRow:
+    """One (V,) logits row of a tick's serve step, left on the device.
+
+    ``token`` is the row's greedy token, which the step took on the device
+    and the tick fetched with the others; the row's logits come to the host
+    only when asked for (``np.asarray(row)``), each copy counted in
+    ``stats["host_logit_rows"]``.  Valid for the tick that made it."""
+
+    __slots__ = ("token", "shape", "_logits", "_at", "_stats")
+
+    def __init__(self, logits: jax.Array, at: tuple, token: int,
+                 stats: Dict):
+        self.token = token
+        self.shape = logits.shape[-1:]
+        self._logits, self._at, self._stats = logits, at, stats
+
+    def __array__(self, dtype=None, copy=None):
+        self._stats["host_logit_rows"] += 1
+        return np.asarray(self._logits[self._at], dtype)
+
+
 @dataclasses.dataclass
 class _Slot:
     req: Request
@@ -404,6 +425,10 @@ class ServeEngine:
                        "spec_k": self._spec_k, "spec_drafted": 0,
                        "spec_accepted": 0, "spec_rejected": 0,
                        "spec_rollbacks": 0, "sampled_slot_ticks": 0,
+                       # logits rows copied to the host: one per token
+                       # drawn at a temperature (greedy tokens come from
+                       # the step's own argmax)
+                       "host_logit_rows": 0,
                        # robustness accounting: slot preemptions and how
                        # their resumes went (park promoted back vs history
                        # re-prefilled), deadline aborts, backpressure
@@ -1103,10 +1128,14 @@ class ServeEngine:
             s.n_indexed += 1
 
     # -- sampling / bookkeeping -------------------------------------------
-    def _sample(self, req: Request, logits_row: np.ndarray,
-                ordinal: int) -> int:
+    def _sample(self, req: Request, row: _LogitsRow, ordinal: int) -> int:
         """One token from a (V,) logits row: greedy argmax at temperature 0,
         seeded temperature/top-k sampling otherwise.
+
+        ``row`` is a handle on the row, which stays on the device: at
+        temperature 0 the token is the step's own argmax (``row.token``, the
+        first maximum of the same logits) and nothing is copied; otherwise
+        the row is copied to the host and sampled there.
 
         ``ordinal`` is the emission index within the request (==
         ``len(req.out_tokens)`` at draw time), and the RNG is keyed
@@ -1119,8 +1148,8 @@ class ServeEngine:
         a rejected draft.  Consequence: transcripts are token-identical
         with speculation on or off at ANY temperature, not just greedy."""
         if req.temperature == 0.0:
-            return int(np.argmax(logits_row))
-        logit = logits_row.astype(np.float64) / req.temperature
+            return row.token
+        logit = np.asarray(row, np.float64) / req.temperature
         if req.top_k is not None and req.top_k < logit.size:
             kth = np.partition(logit, -req.top_k)[-req.top_k]
             logit = np.where(logit >= kth, logit, -np.inf)
@@ -1297,8 +1326,9 @@ class ServeEngine:
         if n == 0:
             return state, results
         with spans.span(spans.DISPATCH):
-            logits, state = self._ragged_step(self.params, state, tokens, slot,
-                                              q_pos, seq_idx, valid, logit_idx)
+            logits, greedy, state = self._ragged_step(
+                self.params, state, tokens, slot, q_pos, seq_idx, valid,
+                logit_idx)
         self._stats["ragged_ticks"] += 1
         self._stats["packed_tokens"] += n
         if self._kernel_visits is not None:
@@ -1308,9 +1338,13 @@ class ServeEngine:
             self._stats["attn_kv_tiles"] += tiles
         if sampling:
             with spans.span(spans.WAIT):
-                jax.block_until_ready(logits)
+                jax.block_until_ready(greedy)
             with spans.span(spans.FETCH):
-                rows = np.asarray(logits)  # (B, V), or (B, R, V) with spec
+                toks = np.asarray(greedy)  # (B,), or (B, R) with spec
+
+            def row(*at):
+                return _LogitsRow(logits, at, int(toks[at]), self._stats)
+
             with spans.span(spans.SAMPLE):
                 self._stats["sampled_slot_ticks"] += len(sampling)
                 accepted: Dict[int, int] = {}
@@ -1322,8 +1356,8 @@ class ServeEngine:
                     # CHECKS draft j+1 and, on mismatch or exhaustion, IS the
                     # correction/bonus token — the chain always emits >= 1
                     j = 0
-                    tok = self._sample(req, rows[b, 0] if self._spec_k
-                                       else rows[b], len(req.out_tokens))
+                    tok = self._sample(req, row(b, 0) if self._spec_k
+                                       else row(b), len(req.out_tokens))
                     while True:
                         self._finish_token(b, tok, results)
                         if (self.slots[b] is None or j >= len(drafts)
@@ -1331,7 +1365,7 @@ class ServeEngine:
                             break
                         j += 1
                         self._stats["spec_accepted"] += 1
-                        tok = self._sample(req, rows[b, j],
+                        tok = self._sample(req, row(b, j),
                                            len(req.out_tokens))
                     accepted[b] = j
                 if drafted:

@@ -70,6 +70,7 @@ from __future__ import annotations
 from typing import Dict
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelCfg
@@ -109,8 +110,13 @@ def make_ragged_step(cfg: ModelCfg, *, width: int, flash_decode: bool = False):
     """Build the single ragged serving program (see ``models.model.ragged_step``).
 
     Returns ``f(params, state, tokens, slot, q_pos, seq_idx, valid,
-    logit_idx) -> (logits (B, V), new_state)`` with all pack vectors (T,)
-    and ``logit_idx`` (B,).  Jit it with ``donate_argnums=(1,)``
+    logit_idx) -> (logits (B, V), greedy (B,) int32, new_state)`` with all
+    pack vectors (T,) and ``logit_idx`` (B,); with a (B, R) ``logit_idx``
+    the logits are (B, R, V) and ``greedy`` (B, R).  ``greedy`` is each
+    logits row's first maximum, taken on the device so that the host copies
+    one int32 per row of a greedy request instead of the row's V logits;
+    the logits stay on the device for the rows sampled at a temperature.
+    Jit it with ``donate_argnums=(1,)``
     (``STATE_DONATE_ARGNUM``) — the KV page pools, their int8 scale pools,
     and the recurrent-state carries dominate the state pytree, and donation
     lets XLA scatter the tick's new entries into the existing buffers
@@ -121,9 +127,10 @@ def make_ragged_step(cfg: ModelCfg, *, width: int, flash_decode: bool = False):
 
     def ragged_step(params, state, tokens, slot, q_pos, seq_idx, valid,
                     logit_idx):
-        return M.ragged_step(params, cfg, state, tokens, slot, q_pos,
-                             seq_idx, valid, logit_idx, width=width,
-                             flash_decode=flash_decode)
+        logits, state = M.ragged_step(params, cfg, state, tokens, slot,
+                                      q_pos, seq_idx, valid, logit_idx,
+                                      width=width, flash_decode=flash_decode)
+        return logits, jnp.argmax(logits, -1).astype(jnp.int32), state
 
     return _named(ragged_step, SERVE_STEP)
 

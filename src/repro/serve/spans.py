@@ -9,10 +9,12 @@ TraceMe and records nothing.
 Inside each ``TICK`` (one ``ServeEngine.tick()``, a step annotation
 numbered by the engine's tick counter), in order: ``ADMIT`` (admission
 rounds and preemption), ``PACK`` (building the pack's arrays),
-``DISPATCH`` (enqueueing the serve step), ``WAIT`` (until its logits are
-ready), ``FETCH`` (their copy to the host) and ``SAMPLE`` (sampling and
-booking, speculative verify and rollback).  ``MOVERS`` holds the tier
-traffic a round applies to device state (page gathers and inserts).
+``DISPATCH`` (enqueueing the serve step), ``WAIT`` (until its greedy
+tokens are ready), ``FETCH`` (their copy to the host: one int32 per logits
+row) and ``SAMPLE`` (sampling and booking, speculative verify and
+rollback; a row drawn at a temperature copies its logits here).
+``MOVERS`` holds the tier traffic a round applies to device state (page
+gathers and inserts).
 """
 from __future__ import annotations
 

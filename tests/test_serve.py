@@ -267,6 +267,82 @@ def test_top_k_one_is_greedy(qwen):
     assert got[u1] == got[u2]
 
 
+# a seeded draw at temperature 8 (top-k 50) beside a greedy request, as
+# the engine served it when it still copied every logits row to the host
+SAMPLED_TRANSCRIPT = [147, 100, 100, 362, 362, 362, 335]
+GREEDY_TRANSCRIPT = [308] * 5
+
+
+class _HostArgmax(ServeEngine):
+    """Copies each sampled row through its handle and keeps the host's
+    argmax of it beside the token the engine booked."""
+
+    def _sample(self, req, row, ordinal):
+        tok = super()._sample(req, row, ordinal)
+        host = np.asarray(row)
+        assert host.shape == row.shape == (self.cfg.vocab_size,)
+        self.pairs.append((int(np.argmax(host)), tok, host.dtype))
+        return tok
+
+
+@pytest.mark.parametrize("spec_k", [0, 3], ids=["plain", "speculative"])
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_greedy_tokens_equal_host_argmax(kv_dtype, spec_k):
+    """The step's device argmax is the first maximum of the same logits
+    the host would read, for every row sampled, verify rows included, at
+    the activation dtype the benchmark's configurations serve in.  The
+    tied embedding's second half copies its first, so every logit has an
+    equal twin and only the first maximum matches."""
+    cfg = get_config("qwen2-1.5b", smoke=True)
+    params = M.init_params(KEY, cfg)
+    emb = params["embed"]["tok_embed"]
+    half = cfg.vocab_size // 2
+    params["embed"]["tok_embed"] = emb.at[half:].set(emb[:half])
+    eng = _HostArgmax(params, cfg, batch_size=3, cache_len=CACHE,
+                      page_size=8, prefill_chunk=16, token_budget=24,
+                      kv_dtype=kv_dtype, spec_k=spec_k)
+    eng.pairs = []
+    rng = np.random.RandomState(9)
+    for L in (5, 11, 20):
+        eng.submit(np.tile(rng.randint(0, cfg.vocab_size, 4), L // 4 + 1)[:L],
+                   max_tokens=8)
+    eng.run()
+    assert eng.pairs and all(h == t < half for h, t, _ in eng.pairs), \
+        eng.pairs
+    assert {d for *_, d in eng.pairs} == {np.dtype(cfg.dtype)}
+    assert eng.stats["host_logit_rows"] == len(eng.pairs)
+    if spec_k:
+        assert eng.stats["spec_accepted"] > 0
+
+
+def test_greedy_run_copies_no_logits_row(qwen):
+    cfg, params = qwen
+    eng, uids, got = _serve(cfg, params, _prompts(cfg, [5, 30, 17], seed=3),
+                            max_tokens=6)
+    assert all(len(got[u]) == 6 for u in uids)
+    assert eng.stats["sampled_slot_ticks"] > 0
+    assert eng.stats["host_logit_rows"] == 0
+
+
+@pytest.mark.parametrize("spec_k", [0, 2], ids=["plain", "speculative"])
+def test_sampled_rows_alone_copy_their_logits(qwen, spec_k):
+    """A request drawn at a temperature copies one logits row per token it
+    draws, its greedy neighbour none, and both transcripts are the ones
+    the engine gave when it copied every row."""
+    cfg, params = qwen
+    sampled, greedy = _prompts(cfg, [9, 13], seed=42)
+    eng = ServeEngine(params, cfg, batch_size=2, cache_len=CACHE,
+                      page_size=8, prefill_chunk=16, token_budget=24,
+                      spec_k=spec_k)
+    u0 = eng.submit(sampled, max_tokens=7, temperature=8.0, top_k=50,
+                    seed=2024)
+    u1 = eng.submit(greedy, max_tokens=5)
+    got = eng.run()
+    assert got[u0] == SAMPLED_TRANSCRIPT
+    assert got[u1] == GREEDY_TRANSCRIPT
+    assert eng.stats["host_logit_rows"] == len(SAMPLED_TRANSCRIPT)
+
+
 def test_sampling_validation(qwen):
     cfg, params = qwen
     eng = ServeEngine(params, cfg, batch_size=2, cache_len=32, page_size=8)

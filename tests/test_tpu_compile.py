@@ -169,21 +169,10 @@ def _pool_sized_ops(text, shapes):
             and not (op == "fusion" and roots.get(calls) == "scatter")]
 
 
-@pytest.mark.parametrize("arch,batch,n_pages,chips", [
-    ("qwen2-1.5b",) + CHAT + (1,),
-    ("qwen1.5-4b",) + TP4 + (4,),
-], ids=["qwen2-1.5b.chat-cell", "qwen1.5-4b.tp4-cell"])
-def test_ragged_serve_step_updates_pools_in_place(topo, monkeypatch, arch,
-                                                  batch, n_pages, chips):
+def _compile_serve_step(topo, arch, batch, n_pages, chips):
     """The engine's ragged serve step at the cell's published widths and
     per-chip pool, two layers deep, on one chip or on the 2x2 mesh by KV
-    head: no instruction but a parameter, a tuple element, a bitcast, the
-    layer loop or an in-place scatter yields a value of a stacked pool's or
-    one layer's pool's shape (a copy, slice or update there moves a whole
-    pool every tick), and every pool leaf of the donated state is aliased
-    to an output."""
-    # the CPU backend would pick the kernel's interpret mode
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    head: (compiled, state shapes, arguments)."""
     cfg = get_config(arch)
     cfg = dataclasses.replace(cfg, param_dtype="bfloat16", stages=tuple(
         dataclasses.replace(st, repeats=2) for st in cfg.stages))
@@ -217,7 +206,41 @@ def test_ragged_serve_step_updates_pools_in_place(topo, monkeypatch, arch,
             jax.ShapeDtypeStruct((T,), jnp.bool_, sharding=rep),
             jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=rep))
     with eng._ctx():
-        compiled = eng._ragged_step.lower(*args).compile()
+        return eng._ragged_step.lower(*args).compile(), state, args
+
+
+@pytest.fixture(scope="module")
+def serve_step(topo):
+    """``_compile_serve_step``, compiled once per cell for this module's
+    tests."""
+    compiled = {}
+
+    def get(*cell):
+        if cell not in compiled:
+            with pytest.MonkeyPatch.context() as mp:
+                # the CPU backend would pick the kernel's interpret mode
+                mp.setattr(ops, "default_interpret", lambda: False)
+                compiled[cell] = _compile_serve_step(topo, *cell)
+        return compiled[cell]
+
+    return get
+
+
+SERVE_CELLS = pytest.mark.parametrize("arch,batch,n_pages,chips", [
+    ("qwen2-1.5b",) + CHAT + (1,),
+    ("qwen1.5-4b",) + TP4 + (4,),
+], ids=["qwen2-1.5b.chat-cell", "qwen1.5-4b.tp4-cell"])
+
+
+@SERVE_CELLS
+def test_ragged_serve_step_updates_pools_in_place(serve_step, arch, batch,
+                                                  n_pages, chips):
+    """The serve step (``_compile_serve_step``): no instruction but a
+    parameter, a tuple element, a bitcast, the layer loop or an in-place
+    scatter yields a value of a stacked pool's or one layer's pool's shape
+    (a copy, slice or update there moves a whole pool every tick), and
+    every pool leaf of the donated state is aliased to an output."""
+    compiled, state, args = serve_step(arch, batch, n_pages, chips)
     text = compiled.as_text()
     _assert_kernel(compiled)
 
@@ -232,3 +255,16 @@ def test_ragged_serve_step_updates_pools_in_place(topo, monkeypatch, arch,
     pools = _pool_leaf_indices(args, STATE_DONATE_ARGNUM)
     assert len(pools) == 2
     assert set(pools) <= set(_compiled_alias_params(text)), pools
+
+
+@SERVE_CELLS
+def test_ragged_serve_step_returns_greedy_tokens(serve_step, arch, batch,
+                                                 n_pages, chips):
+    """Beside the (B, V) logits, which stay on the device, the step returns
+    each row's greedy token as s32[B]: all a greedy tick copies to the
+    host.  Under the mesh it is replicated like the logits."""
+    compiled, _, _ = serve_step(arch, batch, n_pages, chips)
+    logits, greedy, _ = compiled.out_info
+    assert logits.shape == (batch, get_config(arch).vocab_size)
+    assert (greedy.shape, greedy.dtype) == ((batch,), jnp.int32)
+    assert greedy.sharding.is_fully_replicated
